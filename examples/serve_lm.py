@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.model import LM
 from repro.serving import Request, ServingEngine
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=True)
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))

@@ -19,6 +19,7 @@ import jax
 
 from repro.configs.base import ArchConfig, get_config
 from repro.data import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.model import LM
 from repro.optim import adamw
 from repro.train import Trainer
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.arch:
         cfg = get_config(args.arch, reduced=True)
     else:

@@ -2,7 +2,7 @@
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec), with its jit wrapper in
 ops.py and its pure-jnp oracle in ref.py. Validated in interpret mode on CPU;
-TPU (v5e) is the compilation target.
+TPU (v5e) is the compilation target and the default (``interpret=False``).
 """
 from . import ops, ref
 

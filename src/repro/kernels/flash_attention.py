@@ -69,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B, Hq, S, D); k/v: (B, Hkv, S, D), Hq % Hkv == 0 (GQA)."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]

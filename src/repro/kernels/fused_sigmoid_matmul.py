@@ -40,7 +40,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k_blocks: int):
                    static_argnames=("blk_m", "blk_n", "blk_k", "interpret"))
 def fused_sigmoid_matmul(x: jax.Array, w: jax.Array, *, blk_m: int = 128,
                          blk_n: int = 128, blk_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool = False) -> jax.Array:
     m, k = x.shape
     k2, n = w.shape
     assert k == k2

@@ -7,7 +7,9 @@ value (select clause), producing the expert-sorted activation buffer that the
 per-expert GEMMs consume. The combine side (group-by token, sum) reuses the
 ``relational_matmul`` aggregation.
 
-Scalar-prefetched gather, one assignment row per grid step.
+Scalar-prefetched gather, one assignment row per grid step. Tokens are
+viewed as ``(T, 1, d)`` and gates as ``(S, 1, 1)`` so that every block's
+last two dimensions equal the array's, as the TPU's tiling rule requires.
 """
 from __future__ import annotations
 
@@ -25,22 +27,24 @@ def _kernel(idx_ref, x_ref, gates_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def moe_dispatch(x: jax.Array, sort_idx: jax.Array, gates: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """out[s, :] = gates[s] · x[sort_idx[s], :] for expert-sorted slots s."""
     (slots,) = sort_idx.shape
-    _, d = x.shape
+    t, d = x.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(slots,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda s, idx_ref: (idx_ref[s], 0)),
-            pl.BlockSpec((1, 1), lambda s, idx_ref: (s, 0)),
+            pl.BlockSpec((1, 1, d), lambda s, idx_ref: (idx_ref[s], 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda s, idx_ref: (s, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda s, idx_ref: (s, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda s, idx_ref: (s, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, 1, d), x.dtype),
         interpret=interpret,
-    )(sort_idx.astype(jnp.int32), x, gates.reshape(-1, 1))
+    )(sort_idx.astype(jnp.int32), x.reshape(t, 1, d),
+      gates.reshape(slots, 1, 1))
+    return out.reshape(slots, d)

@@ -7,7 +7,9 @@ prefetched (scalar memory), and the BlockSpec index_map steers each grid
 step's DMA to the addressed embedding row — HBM traffic is |ids| · d instead
 of the |ids| · V one-hot join.
 
-Rows are fetched in blocks of ``blk_t`` ids × full d.
+One id per grid step. The table is viewed as ``(V, 1, d)`` so that each
+step's ``(1, 1, d)`` block has the array's own last two dimensions, which
+the TPU's (8, 128) tiling rule requires of a block this thin.
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ def _kernel(ids_ref, table_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def onehot_embed(ids: jax.Array, table: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """out[t, :] = table[ids[t], :]  (onehot(ids) @ table)."""
     (t,) = ids.shape
     v, d = table.shape
@@ -34,12 +36,14 @@ def onehot_embed(ids: jax.Array, table: jax.Array, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, d), lambda i, ids_ref: (ids_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, ids_ref: (i, 0)),
+        in_specs=[pl.BlockSpec((1, 1, d),
+                               lambda i, ids_ref: (ids_ref[i], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, ids_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, d), table.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), table)
+    )(ids.astype(jnp.int32), table.reshape(v, 1, d))
+    return out.reshape(t, d)

@@ -1,11 +1,13 @@
 """Public, jit-compiled entry points for the kernel package.
 
-Every op dispatches between the Pallas kernel (TPU target; ``interpret=True``
-executes the kernel body on CPU for validation) and the pure-jnp reference
+Every op dispatches between the Pallas kernel and the pure-jnp reference
 path (used by the dry-run so XLA's SPMD partitioner sees plain HLO).
 
-On real TPU hardware the ``use_pallas=True`` path compiles the Mosaic
-kernels; this container is CPU-only, so tests exercise interpret mode.
+``use_pallas=True`` compiles the Mosaic kernel for the TPU; off a TPU it
+fails rather than fall back. Pass ``interpret=True`` (through ``**kw``) to
+execute the kernel body in the Pallas interpreter instead, as the CPU
+tests do. ``tests/test_tpu_compile.py`` compiles every kernel for a
+described v5e chip.
 """
 from __future__ import annotations
 

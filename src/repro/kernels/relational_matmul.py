@@ -7,8 +7,9 @@ relation through VMEM and keeps only an O(block) accumulator — the
 "sort-based aggregation with continuous output" of the paper's §8:
 
   grid = (n/blk_n, nnz/blk_t); for each tuple block
-    1. JOIN      gather the matching rhs rows (``b[col_ids]``) from the
-                 VMEM-resident rhs column block         (HBM→VMEM once per j)
+    1. JOIN      one-hot(col_ids) · rhs — the equi-join on ``m.j = n.i``
+                 expressed as an MXU matmul against the VMEM-resident rhs
+                 column block                           (HBM→VMEM once per j)
     2. SELECT    scale by the tuple values
     3. GROUP BY  one-hot(row_ids)ᵀ · scaled — the segment sum expressed as an
                  MXU matmul, so the aggregation runs on the systolic array
@@ -18,8 +19,14 @@ Padding tuples carry ``row_ids == m`` → their one-hot row is all-zero, which
 drops them exactly like a non-matching inner-join tuple.
 
 VMEM working set per grid cell:
-  rhs block (k × blk_n) + tuple block (3 × blk_t) + one-hot (blk_t × m)
-  + accumulator (m × blk_n);  defaults keep this ≲ 8 MiB for m, k ≤ 2048.
+  rhs block (k × blk_n) + tuple block (3 × blk_t) + one-hots
+  (blk_t × k, blk_t × m) + accumulator (m × blk_n); about 13 MiB at the
+  MNIST shapes (m = 2000, k = 784) with the defaults.
+
+On the TPU ``blk_t`` must be a multiple of 1024: XLA tiles a 1-D int32
+array there in 1024-element tiles, and a kernel block must match that
+tiling. Callers pad the relation with ``row_ids == m`` tuples to whole
+blocks.
 """
 from __future__ import annotations
 
@@ -42,14 +49,20 @@ def _kernel(rows_ref, cols_ref, vals_ref, b_ref, o_ref, acc_ref, *,
     rows = rows_ref[...]                       # (blk_t,) sorted row ids
     cols = cols_ref[...]                       # (blk_t,) inner index
     vals = vals_ref[...]                       # (blk_t,)
-    rhs = b_ref[...]                           # (k, blk_n) clustered rhs
+    rhs = b_ref[...].astype(jnp.float32)       # (k, blk_n) clustered rhs
+    k = rhs.shape[0]
 
-    joined = rhs[cols]                         # JOIN: gather matching rows
+    # JOIN on m.j = n.i via one-hot · MXU
+    col_hot = (cols[:, None] ==
+               jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+               ).astype(jnp.float32)
+    joined = jnp.dot(col_hot, rhs, preferred_element_type=jnp.float32)
     scaled = joined * vals[:, None].astype(jnp.float32)   # SELECT m.v·n.v
     # GROUP BY m.i via one-hot · MXU: padding rows (== m) vanish.
-    onehot = (rows[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(onehot.T, scaled,
+    row_hot = (rows[:, None] ==
+               jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+               ).astype(jnp.float32)
+    acc_ref[...] += jnp.dot(row_hot.T, scaled,
                             preferred_element_type=jnp.float32)
 
     @pl.when(t == n_tuple_blocks - 1)
@@ -59,8 +72,9 @@ def _kernel(rows_ref, cols_ref, vals_ref, b_ref, o_ref, acc_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("m", "blk_t", "blk_n", "interpret"))
 def relational_matmul(row_ids: jax.Array, col_ids: jax.Array, vals: jax.Array,
-                      b: jax.Array, m: int, *, blk_t: int = 256,
-                      blk_n: int = 128, interpret: bool = True) -> jax.Array:
+                      b: jax.Array, m: int, *, blk_t: int = 1024,
+                      blk_n: int = 128, interpret: bool = False
+                      ) -> jax.Array:
     """out (m, n) = group-by-sum of the joined relation; b is (k, n)."""
     nnz = row_ids.shape[0]
     k, n = b.shape

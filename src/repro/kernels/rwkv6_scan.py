@@ -11,6 +11,8 @@ r/k/v/w input stream + the output — the same accumulator pattern as
     o_t = r_t · (S + diag(u) k_t v_tᵀ);   S ← diag(w_t) S + k_t v_tᵀ
 
 grid = (B·H, S/blk_t); the t dimension is sequential (scratch carries S).
+``u`` is viewed as ``(BH, 1, N)`` so that its ``(1, 1, N)`` block has the
+array's own last two dimensions, as the TPU's tiling rule requires.
 """
 from __future__ import annotations
 
@@ -30,7 +32,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sf_ref,
     def _init():
         s_scr[...] = s0_ref[0]
 
-    u_col = u_ref[...].T                             # (N, 1): scales k-dim
+    u_col = u_ref[0].T                               # (N, 1): scales k-dim
 
     def step(i, S):
         r_i = r_ref[0, i][None, :]                   # (1, N)
@@ -52,7 +54,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sf_ref,
 
 @functools.partial(jax.jit, static_argnames=("blk_t", "interpret"))
 def rwkv6_scan(r, k, v, w, u, s0, *, blk_t: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """r/k/v/w: (BH, S, N) f32; u: (BH, N); s0: (BH, N, N).
     Returns (o (BH, S, N), s_fin (BH, N, N))."""
     bh, s, n = r.shape
@@ -69,7 +71,7 @@ def rwkv6_scan(r, k, v, w, u, s0, *, blk_t: int = 128,
             pl.BlockSpec((1, blk_t, n), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, blk_t, n), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, blk_t, n), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, n), lambda b, t: (b, 0)),
+            pl.BlockSpec((1, 1, n), lambda b, t: (b, 0, 0)),
             pl.BlockSpec((1, n, n), lambda b, t: (b, 0, 0)),
         ],
         out_specs=[
@@ -82,4 +84,4 @@ def rwkv6_scan(r, k, v, w, u, s0, *, blk_t: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(bh, 1, n), s0)

@@ -31,7 +31,8 @@ from repro.launch.sharding import (batch_shardings, cache_shardings,
 from repro.launch.specs import SDS, batch_specs, cache_specs, params_specs
 from repro.nn.model import LM
 from repro.optim.optimizers import adamw
-from repro.roofline.analysis import analyze, model_flops
+from repro.roofline.analysis import (Roofline, model_flops,
+                                     parse_collectives, peaks)
 from repro.train.trainer import make_train_step
 
 
@@ -115,9 +116,12 @@ def build_lowered(cfg, shape, mesh, fsdp: bool = True):
                 p_shapes, b_shapes, c_shapes, SDS((), jnp.int32))
 
 
+#: the production meshes are v5e pods; the roofline terms use its peaks
+DEVICE_KIND = "TPU v5 lite"
+
+
 def _costs(compiled):
-    from repro.roofline.analysis import cost_analysis, parse_collectives
-    ca = cost_analysis(compiled)  # jax 0.4.3x returns a list of dicts
+    ca = compiled.cost_analysis() or {}
     colls = parse_collectives(compiled.as_text())
     return (float(ca.get("flops", 0.0)),
             float(ca.get("bytes accessed", 0.0)), colls)
@@ -184,16 +188,17 @@ def lower_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     mem = compiled.memory_analysis()
     mf = model_flops(cfg, shape, n_chips)
     xf, xb = ssm_scan_corrections(cfg, shape, n_chips)
-    from repro.roofline.analysis import HBM_BW, LINK_BW, PEAK_FLOPS, Roofline
+    chip = peaks(DEVICE_KIND)
     flops += xf
     hbm += xb
-    terms = {"compute": flops / PEAK_FLOPS, "memory": hbm / HBM_BW,
-             "collective": wire / LINK_BW}
+    terms = {"compute": flops / chip.flops, "memory": hbm / chip.hbm_bw,
+             "collective": wire / chip.link_bw}
     rl = Roofline(flops=flops, hbm_bytes=hbm, wire_bytes=wire,
                   compute_s=terms["compute"], memory_s=terms["memory"],
                   collective_s=terms["collective"],
                   bottleneck=max(terms, key=terms.get),
-                  model_flops=mf, collectives=by_kind)
+                  peak_flops=chip.flops, model_flops=mf,
+                  collectives=by_kind)
     record = {
         "arch": arch_id, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",

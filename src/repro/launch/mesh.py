@@ -13,9 +13,9 @@ same per-shard batches as its dense data-parallel twin.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 
 import jax
+from jax.sharding import AxisType
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,36 +57,13 @@ def shard_slices(n_rows: int, n_shards: int) -> list[slice]:
         start = stop
     return out
 
-try:  # jax ≥ 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x has neither AxisType nor the kwarg
-    AxisType = None
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    AxisType is not None
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-
-def abstract_mesh(shape, axis_names):
-    """``jax.sharding.AbstractMesh`` across jax versions: ≥0.5 takes
-    ``(shape, axis_names)``; 0.4.x takes a tuple of (name, size) pairs."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(shape, axis_names)
-    except TypeError:  # 0.4.x shape_tuple signature
-        return AbstractMesh(tuple(zip(axis_names, shape)))
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) = (data, model) — 256 chips (v5e pod).
     Multi-pod: (2, 16, 16) = (pod, data, model) — 512 chips across DCI."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)  # Auto is the 0.4.x default
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -9,6 +9,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.model import LM
 from repro.serving import Request, ServingEngine
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--max-len", type=int, default=64)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     lm = LM(cfg)
     params = lm.init(jax.random.PRNGKey(0))

@@ -1,8 +1,8 @@
 """Production training launcher.
 
 On a real fleet each host runs this with its process index; here it runs
-the same code path single-host. ``--dry-run-mesh`` routes through the
-512-device placeholder mesh (see dryrun.py for the pure-AOT variant).
+the same code path single-host (``dryrun.py`` compiles the production
+meshes ahead of time, in a process of its own).
 
     PYTHONPATH=src python -m repro.launch.train --arch yi_6b --reduced \
         --steps 50 --seq 128 --batch 8
@@ -13,6 +13,7 @@ import jax
 
 from repro.configs.base import get_config
 from repro.data import TokenPipeline, stub_frontend_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.model import LM
 from repro.optim import adamw
 from repro.train import Trainer
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     lm = LM(cfg)
     if cfg.stub_frontend:

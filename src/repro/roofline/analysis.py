@@ -4,11 +4,12 @@
     memory     = HLO_bytes_per_chip / HBM_bw
     collective = wire_bytes_per_chip / link_bw
 
-TPU v5e constants (assignment): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+The peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+a kind that is not in the table is an error, never a default.
 
-``cost_analysis()`` on the SPMD-partitioned executable reports *per-device*
-FLOPs/bytes (verified in tests). Collective bytes are not in cost_analysis:
+``compiled.cost_analysis()`` on the SPMD-partitioned executable reports
+*per-device* FLOPs/bytes (verified in tests). Collective bytes are not in
+cost_analysis:
 we parse ``compiled.as_text()`` (post-partitioning HLO) and sum the result
 sizes of all-reduce / all-gather / reduce-scatter / all-to-all /
 collective-permute ops, converted to wire bytes with ring-algorithm factors:
@@ -26,9 +27,35 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one device kind."""
+
+    flops: float       # bf16 FLOP/s
+    hbm_bw: float      # HBM bytes/s
+    link_bw: float     # bytes/s per ICI link
+    source: str
+
+
+#: Peaks per ``device_kind`` (the string JAX reports for the device).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip "
+               "interconnect (200 GB/s over 4 ICI links)"),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; raises on an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
@@ -115,6 +142,7 @@ class Roofline:
     memory_s: float
     collective_s: float
     bottleneck: str
+    peak_flops: float             # of the chip the terms assume
     model_flops: float = 0.0      # 6·N·D (per chip share)
     collectives: dict | None = None
 
@@ -130,44 +158,8 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Fraction of the compute roofline achieved at the modelled step
         time: MODEL_FLOPS / (peak · step_time)."""
-        return (self.model_flops / PEAK_FLOPS) / self.step_s \
+        return (self.model_flops / self.peak_flops) / self.step_s \
             if self.step_s else 0.0
-
-
-def cost_analysis(compiled) -> dict:
-    """Version-tolerant ``compiled.cost_analysis()``.
-
-    jax ≤0.4.30 returns a dict, jax 0.4.31–0.4.3x returns a ONE-element
-    list of dicts (one per executable), newer jax returns a dict again;
-    ``None`` shows up for executables without cost info.  Always returns a
-    plain (possibly empty) dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
-def analyze(compiled, model_flops_per_chip: float = 0.0,
-            extra_flops: float = 0.0, extra_bytes: float = 0.0) -> Roofline:
-    """``extra_*``: analytic corrections for lax.scan bodies that XLA's
-    cost analysis counts once instead of ×trip-count (the SSM time scans —
-    see EXPERIMENTS.md §Dry-run 'accounting' note)."""
-    ca = cost_analysis(compiled)
-    flops = float(ca.get("flops", 0.0)) + extra_flops
-    hbm = float(ca.get("bytes accessed", 0.0)) + extra_bytes
-    colls = parse_collectives(compiled.as_text())
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    coll_s = colls.wire_bytes / LINK_BW
-    terms = {"compute": compute_s, "memory": memory_s,
-             "collective": coll_s}
-    bottleneck = max(terms, key=terms.get)
-    return Roofline(flops=flops, hbm_bytes=hbm,
-                    wire_bytes=colls.wire_bytes, compute_s=compute_s,
-                    memory_s=memory_s, collective_s=coll_s,
-                    bottleneck=bottleneck,
-                    model_flops=model_flops_per_chip,
-                    collectives=colls.by_kind)
 
 
 def model_flops(cfg, shape, n_chips: int) -> float:

@@ -1,4 +1,7 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs ref.py oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs ref.py oracle.
+
+Every kernel compiles for the TPU by default; these CPU tests ask for
+the interpreter explicitly."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,7 +32,7 @@ def test_relational_matmul_dense_coo(m, k, n, blk_t, blk_n, dtype):
     cols = jnp.tile(jnp.arange(k, dtype=jnp.int32), m)
     vals = a.reshape(-1)
     out = ops.relational_matmul(rows, cols, vals, b, m, use_pallas=True,
-                                blk_t=blk_t, blk_n=blk_n)
+                                interpret=True, blk_t=blk_t, blk_n=blk_n)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref.relational_matmul(
                                    rows, cols, vals, b, m), np.float32),
@@ -45,7 +48,8 @@ def test_relational_matmul_sparse_padding(nnz, pad):
     cols = jnp.asarray(RNG.randint(0, k, nnz + pad), jnp.int32)
     vals = rnd((nnz + pad,))
     out = ops.relational_matmul(rows, cols, vals, b, m, use_pallas=True,
-                                blk_t=min(64, nnz + pad), blk_n=64)
+                                interpret=True, blk_t=min(64, nnz + pad),
+                                blk_n=64)
     np.testing.assert_allclose(out, ref.relational_matmul(rows, cols, vals,
                                                           b, m),
                                rtol=2e-4, atol=2e-5)
@@ -56,7 +60,7 @@ def test_relational_matmul_sparse_padding(nnz, pad):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_sigmoid_matmul(m, k, n, dtype):
     x, w = rnd((m, k), dtype), rnd((k, n), dtype)
-    out = ops.fused_sigmoid_matmul(x, w, use_pallas=True)
+    out = ops.fused_sigmoid_matmul(x, w, use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref.fused_sigmoid_matmul(x, w),
                                           np.float32), **tol(dtype))
@@ -68,7 +72,7 @@ def test_fused_sigmoid_matmul(m, k, n, dtype):
 def test_onehot_embed(t, v, d, dtype):
     ids = jnp.asarray(RNG.randint(0, v, t), jnp.int32)
     table = rnd((v, d), dtype)
-    out = ops.onehot_embed(ids, table, use_pallas=True)
+    out = ops.onehot_embed(ids, table, use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(table[ids], np.float32))
 
@@ -78,7 +82,7 @@ def test_moe_dispatch(t, slots, d):
     x = rnd((t, d))
     idx = jnp.asarray(RNG.randint(0, t, slots), jnp.int32)
     gates = jnp.asarray(RNG.rand(slots), jnp.float32)
-    out = ops.moe_dispatch(x, idx, gates, use_pallas=True)
+    out = ops.moe_dispatch(x, idx, gates, use_pallas=True, interpret=True)
     np.testing.assert_allclose(out, ref.moe_dispatch(x, idx, gates),
                                rtol=1e-6)
 
@@ -93,7 +97,7 @@ def test_flash_attention(b, hq, hkv, s, d, blk, causal, dtype):
     k = rnd((b, hkv, s, d), dtype)
     v = rnd((b, hkv, s, d), dtype)
     out = ops.flash_attention(q, k, v, causal=causal, use_pallas=True,
-                              blk_q=blk, blk_k=blk)
+                              interpret=True, blk_q=blk, blk_k=blk)
     expect = ref.flash_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), **tol(dtype))
@@ -103,7 +107,8 @@ def test_flash_attention_matches_jnp_flash():
     """Pallas kernel ≡ the jnp online-softmax twin used by the models."""
     from repro.nn.layers import attend_flash
     q, k, v = rnd((2, 4, 256, 64)), rnd((2, 2, 256, 64)), rnd((2, 2, 256, 64))
-    a = ops.flash_attention(q, k, v, causal=True, use_pallas=True)
+    a = ops.flash_attention(q, k, v, causal=True, use_pallas=True,
+                            interpret=True)
     b = attend_flash(q, k, v, chunk=128)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
@@ -119,7 +124,8 @@ def test_rwkv6_scan(bh, s, n, blk):
     w = jnp.asarray(RNG.rand(bh, s, n) * 0.5 + 0.4, jnp.float32)
     u = rnd((bh, n))
     s0 = rnd((bh, n, n)) * 0.1
-    o, sf = ops.rwkv6_scan(r, k, v, w, u, s0, use_pallas=True, blk_t=blk)
+    o, sf = ops.rwkv6_scan(r, k, v, w, u, s0, use_pallas=True,
+                           interpret=True, blk_t=blk)
     o_ref, s_ref = ref.rwkv6_scan(r, k, v, w, u, s0)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=3e-4, atol=3e-4)

@@ -177,3 +177,27 @@ def test_cli_respects_tolerance_flag(tmp_path):
     r = _run_cli("--baseline", str(base), "--fresh", str(fresh),
                  "--tolerance", "3.0")
     assert r.returncode == 0, r.stdout + r.stderr
+
+
+def test_gate_parent_never_initialises_a_jax_backend(tmp_path):
+    """The gate starts benchmark children that may need the chip, which
+    belongs to one process at a time: the parent may import JAX but must
+    never initialise a backend."""
+    rep = tmp_path / "rep.json"
+    rep.write_text(json.dumps(_report(**{"train.s": 1.0})))
+    code = (
+        "import runpy, sys\n"
+        "from jax._src import xla_bridge\n"
+        f"sys.argv = ['gate', '--baseline', {str(rep)!r},"
+        f" '--fresh', {str(rep)!r}]\n"
+        "try:\n"
+        f"    runpy.run_path({str(CHECK)!r}, run_name='__main__')\n"
+        "except SystemExit as e:\n"
+        "    assert e.code == 0, e.code\n"
+        "print('backend', xla_bridge.backends_are_initialized())\n")
+    env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
+           "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "backend False" in r.stdout, r.stdout

@@ -5,9 +5,10 @@ import numpy as np
 import pytest
 
 from repro.configs.base import SHAPES, get_config
-from repro.roofline.analysis import (HBM_BW, LINK_BW, PEAK_FLOPS, Roofline,
-                                     cost_analysis, model_flops,
-                                     parse_collectives)
+from repro.roofline.analysis import (Roofline, model_flops,
+                                     parse_collectives, peaks)
+
+V5E = peaks("TPU v5 lite")
 
 HLO_SAMPLE = """
 HloModule test
@@ -67,9 +68,8 @@ class TestScanAccounting:
 
         xs = jax.ShapeDtypeStruct((64, 128), jnp.float32)
         ws = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-        # the analysis.cost_analysis shim unwraps jax 0.4.3x's list return
-        f_scan = cost_analysis(jax.jit(scanned).lower(xs, ws).compile())
-        f_unr = cost_analysis(jax.jit(unrolled).lower(xs, ws).compile())
+        f_scan = jax.jit(scanned).lower(xs, ws).compile().cost_analysis()
+        f_unr = jax.jit(unrolled).lower(xs, ws).compile().cost_analysis()
         assert f_unr["flops"] == pytest.approx(8 * f_scan["flops"], rel=0.01)
 
     def test_depth_extrapolation_is_exact_for_identical_layers(self):
@@ -84,8 +84,8 @@ class TestScanAccounting:
 
         xs = jax.ShapeDtypeStruct((32, 64), jnp.float32)
         ws = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-        cost = lambda n: cost_analysis(
-            jax.jit(model(n)).lower(xs, ws).compile())["flops"]
+        cost = lambda n: jax.jit(model(n)).lower(
+            xs, ws).compile().cost_analysis()["flops"]
         c1, c2, c5 = cost(1), cost(2), cost(5)
         assert c5 == pytest.approx(c1 + 4 * (c2 - c1), rel=0.01)
 
@@ -120,8 +120,18 @@ class TestModelFlops:
 
     def test_roofline_bottleneck(self):
         r = Roofline(flops=1e15, hbm_bytes=1e12, wire_bytes=1e9,
-                     compute_s=1e15 / PEAK_FLOPS, memory_s=1e12 / HBM_BW,
-                     collective_s=1e9 / LINK_BW, bottleneck="compute",
-                     model_flops=5e14)
-        assert r.step_s == pytest.approx(1e15 / PEAK_FLOPS)
+                     compute_s=1e15 / V5E.flops, memory_s=1e12 / V5E.hbm_bw,
+                     collective_s=1e9 / V5E.link_bw, bottleneck="compute",
+                     peak_flops=V5E.flops, model_flops=5e14)
+        assert r.step_s == pytest.approx(1e15 / V5E.flops)
         assert 0.4 < r.useful_ratio <= 0.5
+
+
+class TestPeaks:
+    def test_v5e_peaks_are_the_published_ones(self):
+        assert (V5E.flops, V5E.hbm_bw) == (197e12, 819e9)
+        assert "TPU v5e" in V5E.source
+
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks("cpu")

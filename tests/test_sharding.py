@@ -3,17 +3,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs.base import SHAPES, get_config
 from repro.launch import sharding as sh
-from repro.launch.mesh import abstract_mesh
 
 
 def mesh(multi=False):
     if multi:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 class FakeKey:

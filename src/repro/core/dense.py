@@ -141,6 +141,14 @@ def eval_node(node: E.Expr, ev) -> jnp.ndarray:
     raise TypeError(f"unknown node {type(node)}")
 
 
+def node_scope(node: E.Expr):
+    """The named scope ``ir.<Kind>`` a node's device ops carry, ``<Kind>``
+    being its class name as the SQL profiler's ``kind`` column has it, so
+    a device trace and a SQL profile name a node alike. Compile-time
+    metadata only; a child evaluated inside keeps its own innermost scope."""
+    return jax.named_scope(f"ir.{type(node).__name__}")
+
+
 def evaluate(roots: list[E.Expr], env: dict[str, jnp.ndarray]) -> list[jnp.ndarray]:
     """Evaluate expression DAG(s) with per-node memoisation (CTE caching)."""
     cache: dict[int, jnp.ndarray] = {}
@@ -148,7 +156,11 @@ def evaluate(roots: list[E.Expr], env: dict[str, jnp.ndarray]) -> list[jnp.ndarr
     def ev(node: E.Expr) -> jnp.ndarray:
         if id(node) in cache:
             return cache[id(node)]
-        out = env[node.name] if isinstance(node, E.Var) else eval_node(node, ev)
+        if isinstance(node, E.Var):
+            out = env[node.name]
+        else:
+            with node_scope(node):
+                out = eval_node(node, ev)
         cache[id(node)] = out
         return out
 

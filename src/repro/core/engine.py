@@ -115,7 +115,8 @@ def sgd_step_fn(loss: E.Expr, wrt: list[E.Var], lr: float, engine: Engine
     def step(weights: dict[str, jnp.ndarray], data_env: dict[str, jnp.ndarray]):
         env = {**weights, **data_env}
         loss_val, grads = vg(env)
-        new_w = {k: weights[k] - lr * grads[k] for k in weights}
+        with jax.named_scope("ir.Update"):
+            new_w = {k: weights[k] - lr * grads[k] for k in weights}
         return new_w, jnp.mean(loss_val)
 
     return step

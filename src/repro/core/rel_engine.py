@@ -30,31 +30,38 @@ def evaluate(roots: list[E.Expr], env: dict[str, RelTensor]) -> list[RelTensor]:
             out = env[node.name]
             if not isinstance(out, RelTensor):
                 raise TypeError(f"relational engine needs RelTensor for {node.name}")
-        elif isinstance(node, E.Const):
-            out = RelTensor.from_dense(
-                jnp.full(node.shape, node.value, dtype=jnp.float32))
-        elif isinstance(node, E.MatMul):
-            out = ev(node.x).matmul(ev(node.y))
-        elif isinstance(node, E.Hadamard):
-            out = ev(node.x).hadamard(ev(node.y))
-        elif isinstance(node, E.Add):
-            out = ev(node.x).add(ev(node.y))
-        elif isinstance(node, E.Sub):
-            out = ev(node.x).sub(ev(node.y))
-        elif isinstance(node, E.Scale):
-            out = ev(node.x).scale(node.c)
-        elif isinstance(node, E.Transpose):
-            out = ev(node.x).transpose()
-        elif isinstance(node, MapDeriv):
-            xv, fxv = ev(node.x), ev(node.fx)
-            out = RelTensor(i=xv.i, j=xv.j, v=node.fn.df(xv.v, fxv.v),
-                            shape=xv.shape)
-        elif isinstance(node, E.Map):
-            out = ev(node.x).map(node.fn.fn)
-        else:  # zoo tier (and ReduceDeriv): shared dense semantics
-            out = RelTensor.from_dense(
-                dense.eval_node(node, lambda c: ev(c).to_dense()))
+        else:
+            with dense.node_scope(node):
+                out = eval_node(node, ev)
         cache[id(node)] = out
         return out
 
     return [ev(r) for r in roots]
+
+
+def eval_node(node: E.Expr, ev) -> RelTensor:
+    """One node's relation; ``ev(child)`` supplies child relations."""
+    if isinstance(node, E.Const):
+        return RelTensor.from_dense(
+            jnp.full(node.shape, node.value, dtype=jnp.float32))
+    if isinstance(node, E.MatMul):
+        return ev(node.x).matmul(ev(node.y))
+    if isinstance(node, E.Hadamard):
+        return ev(node.x).hadamard(ev(node.y))
+    if isinstance(node, E.Add):
+        return ev(node.x).add(ev(node.y))
+    if isinstance(node, E.Sub):
+        return ev(node.x).sub(ev(node.y))
+    if isinstance(node, E.Scale):
+        return ev(node.x).scale(node.c)
+    if isinstance(node, E.Transpose):
+        return ev(node.x).transpose()
+    if isinstance(node, MapDeriv):
+        xv, fxv = ev(node.x), ev(node.fx)
+        return RelTensor(i=xv.i, j=xv.j, v=node.fn.df(xv.v, fxv.v),
+                         shape=xv.shape)
+    if isinstance(node, E.Map):
+        return ev(node.x).map(node.fn.fn)
+    # zoo tier (and ReduceDeriv): shared dense semantics
+    return RelTensor.from_dense(
+        dense.eval_node(node, lambda c: ev(c).to_dense()))

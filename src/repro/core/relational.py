@@ -22,6 +22,10 @@ in canonical row-major order. A ``RelTensor`` may also carry fewer valid
 tuples than its capacity (``nnz``) for genuinely sparse relations such as the
 one-hot matrix or the MoE token→expert assignment; padding rows carry an
 out-of-range ``i`` so the group-by drops them (scatter-drop semantics).
+
+The device ops of each step carry a named scope: ``rel.join`` (the gather-
+multiply), ``rel.group_by`` (the segment sum) and ``rel.pivot`` (dense ↔
+relation), so a profiler trace charges the chip's time to them.
 """
 from __future__ import annotations
 
@@ -52,16 +56,18 @@ class RelTensor:
     def from_dense(x: jax.Array) -> "RelTensor":
         """Pivot a dense matrix into the canonical sorted relation."""
         m, n = x.shape
-        i = jnp.repeat(jnp.arange(m, dtype=jnp.int32), n)
-        j = jnp.tile(jnp.arange(n, dtype=jnp.int32), m)
-        return RelTensor(i=i, j=j, v=x.reshape(-1), shape=(m, n))
+        with jax.named_scope("rel.pivot"):
+            i = jnp.repeat(jnp.arange(m, dtype=jnp.int32), n)
+            j = jnp.tile(jnp.arange(n, dtype=jnp.int32), m)
+            return RelTensor(i=i, j=j, v=x.reshape(-1), shape=(m, n))
 
     def to_dense(self) -> jax.Array:
         """Materialise the relation as a dense matrix (outer-join + coalesce:
         missing cells become 0, as in Listing 5's one-hot construction)."""
         m, n = self.shape
-        out = jnp.zeros((m, n), dtype=self.v.dtype)
-        return out.at[self.i, self.j].add(self.v, mode="drop")
+        with jax.named_scope("rel.pivot"):
+            out = jnp.zeros((m, n), dtype=self.v.dtype)
+            return out.at[self.i, self.j].add(self.v, mode="drop")
 
     def is_canonical(self) -> bool:
         m, n = self.shape
@@ -124,9 +130,11 @@ class RelTensor:
             raise ValueError("rhs of the join must be the canonical relation")
         m, k = self.shape
         n = other.shape[1]
-        rhs_rows = other.v.reshape(k, n)              # clustered by inner index
-        joined = self.v[:, None] * rhs_rows[self.j]   # (cap, n) join result
-        out = jax.ops.segment_sum(joined, self.i, num_segments=m)  # group-by
+        with jax.named_scope("rel.join"):
+            rhs_rows = other.v.reshape(k, n)     # clustered by inner index
+            joined = self.v[:, None] * rhs_rows[self.j]    # (cap, n) joined
+        with jax.named_scope("rel.group_by"):
+            out = jax.ops.segment_sum(joined, self.i, num_segments=m)
         return RelTensor.from_dense(out)
 
     def matmul_intermediate_tuples(self, other: "RelTensor") -> int:

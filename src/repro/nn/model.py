@@ -18,6 +18,10 @@ Entry points consumed by the launcher:
   loss_fn(params, batch) → (scalar loss, metrics)        [train_4k]
   prefill(params, batch) → (last-token logits, cache)    [prefill_32k]
   decode_step(params, batch, cache, pos) → (logits, cache)  [decode shapes]
+
+Named scopes mark the layers on the profiler's clock (compile-time metadata
+only): ``lm.embed``, ``lm.layers`` (every scanned stack), ``lm.attention``,
+``lm.mlp`` (dense FFN and MoE), ``lm.head`` and ``lm.loss``.
 """
 from __future__ import annotations
 
@@ -56,10 +60,10 @@ class LM:
         keeps both paths bitwise identical (same slicing and stacking ops,
         same bf16 rounding), which test_scan_and_unrolled_paths_agree
         pins."""
-        if self.cfg.scan_layers:
-            return jax.lax.scan(body, carry, xs)
-        n = jax.tree.leaves(xs)[0].shape[0]
-        return jax.lax.scan(body, carry, xs, unroll=max(n, 1))
+        unroll = (1 if self.cfg.scan_layers
+                  else max(jax.tree.leaves(xs)[0].shape[0], 1))
+        with jax.named_scope("lm.layers"):
+            return jax.lax.scan(body, carry, xs, unroll=unroll)
 
     def _attend_full(self, q, k, v):
         """Full-sequence attention dispatch (cfg.attn_impl)."""
@@ -158,6 +162,7 @@ class LM:
         return params
 
     # ------------------------------------------------------------- embedding
+    @jax.named_scope("lm.embed")
     def embed_inputs(self, params, batch) -> jax.Array:
         """tokens (B,S) → (B,S,d), or pass through stub-frontend embeds."""
         if "embeds" in batch:
@@ -177,6 +182,7 @@ class LM:
         ang = pos / jnp.power(1e4, i / d)
         return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
+    @jax.named_scope("lm.head")
     def unembed(self, params, x) -> jax.Array:
         norm = (L.rmsnorm if self.cfg.norm == "rmsnorm" else L.layernorm)
         x = norm(params["final_norm"], x)
@@ -185,6 +191,7 @@ class LM:
         return jnp.dot(x, head.astype(x.dtype))
 
     # ------------------------------------------------------ layer-stack body
+    @jax.named_scope("lm.attention")
     def _attn_block(self, p, x, cos, sin, cache=None, pos=None):
         """Returns (out, new_kv) — new_kv is this call's K/V (full-seq) or
         the updated cache slice (decode)."""
@@ -241,15 +248,16 @@ class LM:
                                         cos, sin, cache=cache, pos=pos)
         x = x + attn_out
         h = norm(p["norm2"], x)
-        if "moe" in p:
-            b, s, d = h.shape
-            out, aux = M.moe_ffn(p["moe"], h.reshape(b * s, d),
-                                 _moe_cfg(cfg))
-            x = x + out.reshape(b, s, d)
-        else:
-            x = x + (L.swiglu(p["mlp"], h) if cfg.mlp == "swiglu"
-                     else L.gelu_mlp(p["mlp"], h))
-        return x, aux, kv
+        with jax.named_scope("lm.mlp"):
+            if "moe" in p:
+                b, s, d = h.shape
+                out, aux = M.moe_ffn(p["moe"], h.reshape(b * s, d),
+                                     _moe_cfg(cfg))
+                out = out.reshape(b, s, d)
+            else:
+                out = (L.swiglu(p["mlp"], h) if cfg.mlp == "swiglu"
+                       else L.gelu_mlp(p["mlp"], h))
+        return x + out, aux, kv
 
     def _mla_block_decode(self, p, x, cos, sin, cache, pos):
         """Absorbed-matmul MLA decode: attend in the compressed latent space.
@@ -370,8 +378,10 @@ class LM:
         attn_out, kv = self._attn_block(p["attn"], a_in, cos, sin,
                                         cache=cache, pos=pos)
         h = h + attn_out
-        h = h + L.swiglu(p["mlp"], L.rmsnorm(p["norm2"], h))
-        return x + h, kv
+        m_in = L.rmsnorm(p["norm2"], h)
+        with jax.named_scope("lm.mlp"):
+            m_out = L.swiglu(p["mlp"], m_in)
+        return x + (h + m_out), kv
 
     def _rope_dim(self):
         return (self.cfg.mla.d_rope if self.cfg.mla is not None
@@ -383,21 +393,22 @@ class LM:
             return self._loss_chunked(params, batch)
         logits, aux = self.forward(params, batch)
         labels = batch["labels"]
-        logits = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        if self.cfg.loss_impl == "onehot":
-            # gold logit via masked sum — unlike take_along_axis this never
-            # gathers across the vocab(model)-sharded dim: GSPMD lowers the
-            # reduction to a partial sum + psum (§Perf lever)
-            vpos = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                            logits.ndim - 1)
-            gold = jnp.sum(jnp.where(vpos == labels[..., None], logits,
-                                     0.0), axis=-1)
-        else:
-            gold = jnp.take_along_axis(logits, labels[..., None],
-                                       axis=-1)[..., 0]
-        ce = jnp.mean(lse - gold)
-        return ce + 0.01 * aux, {"ce": ce, "aux": aux}
+        with jax.named_scope("lm.loss"):
+            logits = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            if self.cfg.loss_impl == "onehot":
+                # gold logit via masked sum — unlike take_along_axis this
+                # never gathers across the vocab(model)-sharded dim: GSPMD
+                # lowers the reduction to a partial sum + psum (§Perf lever)
+                vpos = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                                logits.ndim - 1)
+                gold = jnp.sum(jnp.where(vpos == labels[..., None], logits,
+                                         0.0), axis=-1)
+            else:
+                gold = jnp.take_along_axis(logits, labels[..., None],
+                                           axis=-1)[..., 0]
+            ce = jnp.mean(lse - gold)
+            return ce + 0.01 * aux, {"ce": ce, "aux": aux}
 
     def _loss_chunked(self, params, batch):
         """Vocab-streamed cross-entropy: the (B,S,V) f32 logits tensor is
@@ -406,30 +417,36 @@ class LM:
         cfg = self.cfg
         x, aux = self.backbone(params, batch)
         norm = (L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm)
-        xn = norm(params["final_norm"], x)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
+        with jax.named_scope("lm.head"):
+            xn = norm(params["final_norm"], x)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
         labels = batch["labels"]
         b, s_, _ = xn.shape
-        run_max = jnp.full((b, s_), -1e30, jnp.float32)
-        run_se = jnp.zeros((b, s_), jnp.float32)
-        gold = jnp.zeros((b, s_), jnp.float32)
+        with jax.named_scope("lm.loss"):
+            run_max = jnp.full((b, s_), -1e30, jnp.float32)
+            run_se = jnp.zeros((b, s_), jnp.float32)
+            gold = jnp.zeros((b, s_), jnp.float32)
         v = cfg.vocab
         chunk = cfg.loss_chunk
         for lo in range(0, v, chunk):
             hi = min(v, lo + chunk)
-            lc = jnp.dot(xn, head[:, lo:hi].astype(xn.dtype)
-                         ).astype(jnp.float32)
-            m_new = jnp.maximum(run_max, lc.max(axis=-1))
-            run_se = (run_se * jnp.exp(run_max - m_new)
-                      + jnp.exp(lc - m_new[..., None]).sum(axis=-1))
-            run_max = m_new
-            in_rng = (labels >= lo) & (labels < hi)
-            idx = jnp.clip(labels - lo, 0, hi - lo - 1)
-            gval = jnp.take_along_axis(lc, idx[..., None], axis=-1)[..., 0]
-            gold = gold + jnp.where(in_rng, gval, 0.0)
-        ce = jnp.mean(jnp.log(run_se) + run_max - gold)
-        return ce + 0.01 * aux, {"ce": ce, "aux": aux}
+            with jax.named_scope("lm.head"):
+                lc = jnp.dot(xn, head[:, lo:hi].astype(xn.dtype)
+                             ).astype(jnp.float32)
+            with jax.named_scope("lm.loss"):
+                m_new = jnp.maximum(run_max, lc.max(axis=-1))
+                run_se = (run_se * jnp.exp(run_max - m_new)
+                          + jnp.exp(lc - m_new[..., None]).sum(axis=-1))
+                run_max = m_new
+                in_rng = (labels >= lo) & (labels < hi)
+                idx = jnp.clip(labels - lo, 0, hi - lo - 1)
+                gval = jnp.take_along_axis(lc, idx[..., None],
+                                           axis=-1)[..., 0]
+                gold = gold + jnp.where(in_rng, gval, 0.0)
+        with jax.named_scope("lm.loss"):
+            ce = jnp.mean(jnp.log(run_se) + run_max - gold)
+            return ce + 0.01 * aux, {"ce": ce, "aux": aux}
 
     # ------------------------------------------------------------- serving
     def init_cache(self, batch_size: int, max_len: int) -> Any:
@@ -535,17 +552,20 @@ class LM:
     def _mla_block_and_ffn(self, p, x, cos, sin, cache, pos, dense):
         cfg = self.cfg
         norm = L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
-        o, new_cache = self._mla_block_decode(p, norm(p["norm1"], x),
-                                              cos, sin, cache, pos)
+        with jax.named_scope("lm.attention"):
+            o, new_cache = self._mla_block_decode(p, norm(p["norm1"], x),
+                                                  cos, sin, cache, pos)
         x = x + o
         h = norm(p["norm2"], x)
-        if dense or "mlp" in p:
-            x = x + L.swiglu(p["mlp"], h)
-        else:
-            b, s, d = h.shape
-            out, _ = M.moe_ffn(p["moe"], h.reshape(b * s, d), _moe_cfg(cfg))
-            x = x + out.reshape(b, s, d)
-        return x, new_cache
+        with jax.named_scope("lm.mlp"):
+            if dense or "mlp" in p:
+                out = L.swiglu(p["mlp"], h)
+            else:
+                b, s, d = h.shape
+                out, _ = M.moe_ffn(p["moe"], h.reshape(b * s, d),
+                                   _moe_cfg(cfg))
+                out = out.reshape(b, s, d)
+        return x + out, new_cache
 
     def _decode_hybrid(self, params, x, cache, pos, cos, sin):
         cfg = self.cfg
@@ -580,6 +600,7 @@ class LM:
                      (jnp.stack(new_ak), jnp.stack(new_av)))
         return self.unembed(params, x), new_cache
 
+    @jax.named_scope("lm.embed")
     def embed_inputs_decode(self, params, batch, pos):
         if "embeds" in batch:
             x = batch["embeds"].astype(L.COMPUTE_DTYPE)
@@ -623,21 +644,27 @@ class LM:
                 xx = carry
                 norm = L.rmsnorm
                 h = norm(lp["norm1"], xx)
-                q, k, v, c_kv = L.mla_qkv(lp["attn"], h, cfg.n_heads,
-                                          cfg.mla.d_nope, cfg.mla.d_rope,
-                                          cfg.mla.d_v, cos, sin)
-                o = self._attend_full(q, k, v)
-                xx = xx + L.merge_heads(o) @ L.cdt(lp["attn"]["wo"])
+                with jax.named_scope("lm.attention"):
+                    q, k, v, c_kv = L.mla_qkv(lp["attn"], h, cfg.n_heads,
+                                              cfg.mla.d_nope, cfg.mla.d_rope,
+                                              cfg.mla.d_v, cos, sin)
+                    o = self._attend_full(q, k, v)
+                    o = L.merge_heads(o) @ L.cdt(lp["attn"]["wo"])
+                xx = xx + o
                 hh = norm(lp["norm2"], xx)
-                if "moe" in lp:
-                    bb, ss, dd = hh.shape
-                    out, _ = M.moe_ffn(lp["moe"], hh.reshape(bb * ss, dd),
-                                       _moe_cfg(cfg))
-                    xx = xx + out.reshape(bb, ss, dd)
-                else:
-                    xx = xx + L.swiglu(lp["mlp"], hh)
-                k_rope = jnp.dot(h, L.cdt(lp["attn"]["wk_rope"]))
-                k_rope = L.apply_rope(k_rope[:, None], cos, sin)[:, 0]
+                with jax.named_scope("lm.mlp"):
+                    if "moe" in lp:
+                        bb, ss, dd = hh.shape
+                        out, _ = M.moe_ffn(lp["moe"],
+                                           hh.reshape(bb * ss, dd),
+                                           _moe_cfg(cfg))
+                        out = out.reshape(bb, ss, dd)
+                    else:
+                        out = L.swiglu(lp["mlp"], hh)
+                xx = xx + out
+                with jax.named_scope("lm.attention"):
+                    k_rope = jnp.dot(h, L.cdt(lp["attn"]["wk_rope"]))
+                    k_rope = L.apply_rope(k_rope[:, None], cos, sin)[:, 0]
                 return xx, (c_kv, k_rope)
             if "prologue" in params:
                 x, pro_cache = self._scan(body, x, params["prologue"])

@@ -16,6 +16,12 @@ attribution.  This module is the instrument panel:
     training loss, tokens/s, cache hit rate; see
     :mod:`repro.obs.metrics`).
 
+While a collecting tracer's span is open it is also a profiler annotation
+(``jax.profiler.TraceAnnotation``, a TraceMe) of the same name, so
+``serve.step``, ``sql.evaluate`` and the rest land on an XProf capture's
+host plane, on the same clock as the device ops they dispatch.  The span's
+own times stay on the tracer's clock.
+
 ``NullTracer``
     The zero-cost default.  ``span()`` returns a shared no-op singleton
     whose ``__enter__``/``__exit__``/``set`` do nothing — instrumented code
@@ -37,6 +43,8 @@ import threading
 import time
 from contextlib import contextmanager
 
+from jax.profiler import TraceAnnotation
+
 from . import metrics as _metrics
 
 
@@ -51,10 +59,12 @@ class Span:
     still sitting on the thread-local stack (a span opened inside this one
     whose ``__exit__`` never ran — e.g. a generator torn down mid-flight)
     is force-closed and exported too, so one failed query can never leave
-    the stack dirty for the next call."""
+    the stack dirty for the next call.  Each span is also a profiler
+    annotation from enter to close; force-closed descendants end theirs
+    innermost first."""
 
     __slots__ = ("tracer", "name", "attrs", "span_id", "parent_id", "path",
-                 "t0", "t1", "tid", "_closed")
+                 "t0", "t1", "tid", "_closed", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
@@ -67,6 +77,7 @@ class Span:
         self.t1 = None
         self.tid = None
         self._closed = False
+        self._note = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes to an open (or finished) span."""
@@ -91,6 +102,9 @@ class Span:
             self.parent_id = stack[-1].span_id
             self.path = stack[-1].path + "/" + self.name
         stack.append(self)
+        if TraceAnnotation.is_enabled():     # a profiler capture is running
+            self._note = TraceAnnotation(self.name)
+            self._note.__enter__()
         self.t0 = tr._clock()
         return self
 
@@ -104,6 +118,8 @@ class Span:
         self._closed = True
         if self.t1 is None:
             self.t1 = now
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         with self.tracer._lock:
             self.tracer.spans.append(self)
 

@@ -83,6 +83,8 @@ class ServingEngine:
         return {"tokens": jnp.asarray(t)}
 
     def _sample(self, logits) -> int:
+        """The next token, read to the host: one ``serve.host_syncs``."""
+        tracer_of(self).inc("serve.host_syncs")
         if self.temperature == 0.0:
             return int(jnp.argmax(logits))
         self.key, sub = jax.random.split(self.key)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.obs import tracer_of
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
 
 
@@ -26,7 +27,8 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
     With ``grad_accum > 1`` the global batch is split along axis 0 into
     microbatches accumulated in a ``lax.scan`` — activation memory drops by
     the accumulation factor while keeping the same global batch (a standard
-    memory-roofline lever, see §Perf).
+    memory-roofline lever, see §Perf). Clipping and the update run under the
+    named scopes ``train.clip`` and ``train.optimizer``.
     """
     vg = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -52,8 +54,10 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
             loss = loss / grad_accum
             metrics = jax.tree.map(lambda m: m[-1], metrics)
-        grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        params, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("train.clip"):
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+        with jax.named_scope("train.optimizer"):
+            params, opt_state = optimizer.update(grads, opt_state, params)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         return params, opt_state, metrics
 
@@ -125,14 +129,27 @@ class Trainer:
 
     def run(self, key, n_steps: int, log_every: int = 10,
             log_fn=print) -> dict:
+        """Steps ``start..n_steps``, each under the profiler's
+        ``StepTraceAnnotation("train", step_num=step)``, with the spans
+        ``train.batch``, ``train.step``, ``train.read_metrics`` and
+        ``train.checkpoint`` on the active tracer. Its counter
+        ``train.host_syncs`` counts the device→host reads: one
+        ``jax.device_get`` of the metrics per step and one snapshot per
+        checkpoint."""
+        tr = tracer_of(self)
         params, opt_state, start = self.restore_or_init(key)
         for step in range(start, n_steps):
-            batch = self.data.batch_at(step)
-            t0 = time.perf_counter()
-            params, opt_state, metrics = self.step_fn(params, opt_state,
-                                                      batch)
-            metrics = jax.tree.map(float, metrics)
-            dt = time.perf_counter() - t0
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with tr.span("train.batch", step=step):
+                    batch = self.data.batch_at(step)
+                t0 = time.perf_counter()
+                with tr.span("train.step", step=step):
+                    params, opt_state, metrics = self.step_fn(
+                        params, opt_state, batch)
+                with tr.span("train.read_metrics", step=step):
+                    metrics = jax.tree.map(float, jax.device_get(metrics))
+                    tr.inc("train.host_syncs")
+                dt = time.perf_counter() - t0
             straggle = self.monitor.record(step, dt)
             rec = dict(metrics, step=step, seconds=dt, straggler=straggle)
             self.history.append(rec)
@@ -140,8 +157,12 @@ class Trainer:
                 log_fn(f"step {step:5d} loss {metrics['loss']:.4f} "
                        f"({dt * 1e3:.0f} ms){' STRAGGLER' if straggle else ''}")
             if self.ckpt and (step + 1) % self.checkpoint_every == 0:
-                self.ckpt.save(step + 1, (params, opt_state))
+                with tr.span("train.checkpoint", step=step + 1):
+                    self.ckpt.save(step + 1, (params, opt_state))
+                    tr.inc("train.host_syncs")
         if self.ckpt:
-            self.ckpt.save(n_steps, (params, opt_state), blocking=True)
+            with tr.span("train.checkpoint", step=n_steps):
+                self.ckpt.save(n_steps, (params, opt_state), blocking=True)
+                tr.inc("train.host_syncs")
         return {"params": params, "opt_state": opt_state,
                 "history": self.history}

@@ -36,6 +36,12 @@ import jax
 import jax.numpy as jnp
 
 
+def _canonical_indices(m: int, n: int) -> tuple[jax.Array, jax.Array]:
+    """``i`` and ``j`` of the canonical (row-major) ``(m, n)`` relation."""
+    return (jnp.repeat(jnp.arange(m, dtype=jnp.int32), n),
+            jnp.tile(jnp.arange(n, dtype=jnp.int32), m))
+
+
 @partial(jax.tree_util.register_dataclass,
          data_fields=("i", "j", "v"), meta_fields=("shape",))
 @dataclasses.dataclass
@@ -57,8 +63,7 @@ class RelTensor:
         """Pivot a dense matrix into the canonical sorted relation."""
         m, n = x.shape
         with jax.named_scope("rel.pivot"):
-            i = jnp.repeat(jnp.arange(m, dtype=jnp.int32), n)
-            j = jnp.tile(jnp.arange(n, dtype=jnp.int32), m)
+            i, j = _canonical_indices(m, n)
             return RelTensor(i=i, j=j, v=x.reshape(-1), shape=(m, n))
 
     def to_dense(self) -> jax.Array:
@@ -70,6 +75,7 @@ class RelTensor:
             return out.at[self.i, self.j].add(self.v, mode="drop")
 
     def is_canonical(self) -> bool:
+        """One tuple per cell, in row-major order (``from_dense``'s layout)."""
         m, n = self.shape
         return self.capacity == m * n
 
@@ -77,10 +83,18 @@ class RelTensor:
     def transpose(self) -> "RelTensor":
         """``select i as j, j as i, v`` + canonical re-sort.
 
-        The index rename is free; re-establishing the canonical sort order
-        (the clustered index) is a permutation known from the shape alone.
+        The index rename is free. For a canonical relation the re-sort is a
+        permutation known from the shape alone: the values are the dense
+        ``(m, n) → (n, m)`` transpose and the indices those of an ``(n, m)``
+        canonical relation, so no sort runs. A relation with fewer tuples
+        than cells (sparse, or padded) is re-sorted by an ``argsort`` of the
+        transposed key.
         """
         m, n = self.shape
+        if self.is_canonical():
+            i, j = _canonical_indices(n, m)
+            return RelTensor(i=i, j=j, v=self.v.reshape(m, n).T.reshape(-1),
+                             shape=(n, m))
         key = self.j * m + self.i  # int32: capacities here stay < 2^31
         order = jnp.argsort(key)
         return RelTensor(i=self.j[order], j=self.i[order], v=self.v[order],

@@ -1,4 +1,6 @@
 """Core-library tests: relational algebra, Algorithm-1 autodiff, engines."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,15 @@ RNG = np.random.RandomState(0)
 
 def rnd(*shape):
     return jnp.asarray(RNG.randn(*shape), jnp.float32)
+
+
+def assert_same_tuples(got: RelTensor, want: RelTensor):
+    """Bitwise the same relation: shape, and i, j, v in the same order."""
+    assert got.shape == want.shape
+    for a in "ijv":
+        got_a, want_a = getattr(got, a), getattr(want, a)
+        assert got_a.dtype == want_a.dtype
+        np.testing.assert_array_equal(got_a, want_a)
 
 
 # ---------------------------------------------------------------------------
@@ -97,6 +108,65 @@ class TestRelTensor:
         rel = RelTensor.from_dense(a)
         np.testing.assert_allclose(rel.transpose().transpose().to_dense(),
                                    a)
+
+    # the canonical re-sort as a sort: the construction a canonical
+    # relation's transpose must reproduce without one
+    @staticmethod
+    def argsort_transpose(rel):
+        m, n = rel.shape
+        order = jnp.argsort(rel.j * m + rel.i)
+        return RelTensor(i=rel.j[order], j=rel.i[order], v=rel.v[order],
+                         shape=(n, m))
+
+    # 1×n, n×1, square, and the relational cell's 2000:784 (img) and
+    # 2000:200 (a_xh) ratios at CPU sizes
+    SHAPES = [(1, 7), (7, 1), (6, 6), (250, 98), (100, 10)]
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_canonical_transpose_is_the_argsort_construction(self, shape):
+        rel = RelTensor.from_dense(rnd(*shape))
+        for got, want in [(rel.transpose(), self.argsort_transpose(rel)),
+                          (jax.jit(RelTensor.transpose)(rel),
+                           jax.jit(self.argsort_transpose)(rel))]:
+            assert got.shape == shape[::-1] and got.is_canonical()
+            assert_same_tuples(got, want)
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_transpose_twice_is_identity(self, shape):
+        rel = RelTensor.from_dense(rnd(*shape))
+        assert_same_tuples(rel.transpose().transpose(), rel)
+
+    @pytest.mark.parametrize("classes", [2, 3, 10])
+    def test_sparse_one_hot_transposes_by_sort(self, classes):
+        labels = jnp.asarray(RNG.randint(0, classes, 9), jnp.int32)
+        rel = one_hot(labels, classes)
+        assert not rel.is_canonical()
+        t = rel.transpose()
+        assert t.shape == (classes, 9)
+        np.testing.assert_array_equal(t.to_dense(),
+                                      jax.nn.one_hot(labels, classes).T)
+        # re-sorted into the transposed relation's clustered order
+        key = np.asarray(t.i) * 9 + np.asarray(t.j)
+        assert (np.diff(key) > 0).all()
+        np.testing.assert_array_equal(t.transpose().to_dense(),
+                                      rel.to_dense())
+
+    def test_padded_relation_transposes_by_sort(self):
+        """Padding tuples (i == m) stay out of the transposed matrix."""
+        rows = jnp.array([0, 0, 2, 3, 3], jnp.int32)
+        cols = jnp.array([1, 3, 0, 7, 2], jnp.int32)
+        vals = rnd(5)
+        rel = RelTensor(i=jnp.concatenate([rows, jnp.full((3,), 4,
+                                                          jnp.int32)]),
+                        j=jnp.concatenate([cols, jnp.zeros((3,), jnp.int32)]),
+                        v=jnp.concatenate([vals, jnp.ones((3,))]),
+                        shape=(4, 8))
+        expect = np.zeros((4, 8), np.float32)
+        expect[np.asarray(rows), np.asarray(cols)] = np.asarray(vals)
+        t = rel.transpose()
+        assert t.shape == (8, 4) and t.capacity == rel.capacity
+        np.testing.assert_array_equal(t.to_dense(), expect.T)
+        np.testing.assert_array_equal(t.transpose().to_dense(), expect)
 
 
 # ---------------------------------------------------------------------------
@@ -196,6 +266,33 @@ class TestEngines:
             outs[kind] = probs
         np.testing.assert_allclose(outs["dense"], outs["relational"],
                                    rtol=1e-5, atol=1e-6)
+
+    def test_relational_training_query_sorts_nothing(self, monkeypatch):
+        """Every relation the training graph transposes (img, a_xh, w_ho) is
+        canonical, so the compiled query holds no sort, and its weights are
+        bitwise those of the same query re-sorting each transpose by argsort."""
+        spec = nn2sql.MLPSpec(40, 24, 12, 10)
+        g = nn2sql.build_graph(spec)
+        r = np.random.RandomState(5)
+        x = jnp.asarray(r.rand(40, 24), jnp.float32)
+        y = jnp.asarray(jax.nn.one_hot(r.randint(0, 10, 40), 10))
+        w0 = nn2sql.init_weights(spec)
+        eng = Engine("relational")
+
+        def query():
+            return jax.jit(
+                lambda w, x, y: nn2sql.train(g, w, x, y, 4, eng)[0])
+
+        compiled = query().lower(w0, x, y).compile()
+        assert not re.search(r"\bsort\(", compiled.as_text())
+        got = compiled(w0, x, y)
+        monkeypatch.setattr(RelTensor, "transpose",
+                            TestRelTensor.argsort_transpose)
+        sorting = query().lower(w0, x, y).compile()
+        assert re.search(r"\bsort\(", sorting.as_text())
+        want = sorting(w0, x, y)
+        for k in ("w_xh", "w_ho"):
+            np.testing.assert_array_equal(got[k], want[k])
 
 
 # ---------------------------------------------------------------------------

@@ -53,7 +53,7 @@ from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.nn.model import LM  # noqa: E402
 from repro.optim import adamw  # noqa: E402
 from repro.serving import Request, ServingEngine  # noqa: E402
-from repro.train import Trainer, make_train_step  # noqa: E402
+from repro.train import Trainer  # noqa: E402
 
 #: float32 at "highest" against float64: the bound the CPU tests use
 HIGHEST_BOUND = 1e-4
@@ -327,36 +327,27 @@ SHARDED_FLIP_SHARE = 5e-2
 
 
 def phase_sharded(clock, cfg, batch=4, seq=2048, mesh_shape=(2, 2)):
-    from jax.sharding import AxisType
-
-    from repro.launch.sharding import (batch_shardings, opt_shardings,
-                                       param_shardings)
+    from repro.launch.mesh import make_mesh
 
     lm = LM(cfg)
     opt = adamw(LR)
-    step = make_train_step(lm.loss_fn, opt)
     data = TokenPipeline(vocab=cfg.vocab, seq_len=seq, global_batch=batch)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
     with Phase(clock) as ph:
         params = jax.jit(lm.init)(jax.random.PRNGKey(0))
         opt_state = jax.jit(opt.init)(params)
         b = data.batch_at(0)
-        p_sh = param_shardings(jax.eval_shape(lambda: params), mesh)
-        o_sh = opt_shardings(jax.eval_shape(lambda: opt_state), p_sh, mesh)
-        b_sh = batch_shardings(jax.eval_shape(lambda: b), mesh, batch)
         # no donation: placing a replicated leaf may reuse the one-chip
         # buffer, which the one-chip step below still reads
-        sharded = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
-                          out_shardings=(p_sh, o_sh, None))
-        p4, o4, m4 = sharded(jax.device_put(params, p_sh),
-                             jax.device_put(opt_state, o_sh),
-                             jax.device_put(b, b_sh))
+        mesh_tr = Trainer(lm, opt, data, mesh=make_mesh(mesh_shape),
+                          donate=False)
+        p4, o4, m4 = mesh_tr.step_fn(
+            jax.device_put(params, mesh_tr.param_sharding),
+            jax.device_put(opt_state, mesh_tr.opt_sharding),
+            mesh_tr.place_batch(b))
         p4 = jax.tree.map(np.asarray, p4)
         m4 = jax.tree.map(float, m4)
         del o4      # chip 0 needs the room for the one-chip step
-        one_chip = jax.jit(step, donate_argnums=(0, 1))
-        p1, _, m1 = one_chip(params, opt_state, b)
+        p1, _, m1 = Trainer(lm, opt, data).step_fn(params, opt_state, b)
         p1 = jax.tree.map(np.asarray, p1)
         m1 = jax.tree.map(float, m1)
     n = flips = 0

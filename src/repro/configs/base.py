@@ -65,6 +65,16 @@ class ArchConfig:
     stub_frontend: Optional[str] = None   # "audio_frames" | "vision_patches"
     shared_attn_every: int = 0            # zamba2: shared block period
     sub_quadratic: bool = False           # may run long_500k
+    # granite's scalings (neutral defaults add no operation): the token
+    # embedding times ``embedding_multiplier``; attention scores times
+    # ``attention_multiplier`` (None: 1/sqrt(d_head)); each block's
+    # attention and MLP output times ``residual_multiplier`` before its
+    # residual add; logits divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6                # RMSNorm epsilon
     # execution knobs (hillclimbed in §Perf)
     attn_impl: str = "flash"              # flash | chunked | dense
     attn_chunk: int = 0                   # 0 = auto

@@ -1,4 +1,17 @@
-"""Granite-3.0-8B — dense GQA, tied embeddings [hf:ibm-granite/granite-3.0]."""
+"""Granite-3.0-8B — dense GQA, tied embeddings [hf:ibm-granite/granite-3.0].
+
+The published model also scales its embedding (×12), attention scores
+(×1/128, in place of 1/sqrt(128)), each block's output before its
+residual add (×0.22) and its logits (÷16), with RMSNorm eps 1e-5. The
+model implements all of them (``ArchConfig.embedding_multiplier``,
+``attention_multiplier``, ``residual_multiplier``, ``logits_scaling``,
+``norm_eps``), but ``CONFIG`` keeps the neutral defaults: the one-chip
+benchmark cell ``granite_3_8b_l2.train_4x2048`` builds its program from
+``CONFIG`` and compares it with a reference that has none of them. The
+four-chip cell ``granite_3_8b_l8.train_2x2`` sets the published values
+from its own configuration file. Moving ``CONFIG`` onto them goes with
+moving the one-chip cell and its reference.
+"""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(

@@ -66,6 +66,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
+def make_mesh(shape, axes=("data", "model")):
+    """A mesh of the first devices in ``shape`` over ``axes``, every axis
+    ``Auto``: GSPMD places what the sharding rules leave open (the
+    default ``Explicit`` axes would require a sharding on every op)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def data_axes(mesh) -> tuple[str, ...]:
     """The data-parallel axes: ('pod', 'data') multi-pod, ('data',) single."""
     return tuple(n for n in mesh.axis_names if n in ("pod", "data"))

@@ -14,10 +14,36 @@ import jax
 import jax.numpy as jnp
 
 COMPUTE_DTYPE = jnp.bfloat16
+#: mesh axes that split the batch (``launch/mesh.py``'s data axes)
+DATA_AXES = ("pod", "data")
 
 
 def cdt(x):
     return x.astype(COMPUTE_DTYPE)
+
+
+def split_tokens(x, over_model: bool = False):
+    """Activations (B, S, ...) laid out with the batch split over the data
+    axes of the mesh the program is traced under (``jax.set_mesh``) and,
+    ``over_model``, the positions over its ``model`` axis, where the axes
+    divide the dims. Left to itself GSPMD may gather the batch to meet
+    the FSDP-split weights. Without a mesh ``x`` is returned as it is,
+    with nothing added to the program."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return x
+    spec = [None] * x.ndim
+    dp = tuple(a for a in mesh.axis_names if a in DATA_AXES)
+    n = 1
+    for a in dp:
+        n *= mesh.shape[a]
+    if dp and x.shape[0] % n == 0:
+        spec[0] = dp
+    if (over_model and "model" in mesh.axis_names
+            and x.shape[1] % mesh.shape["model"] == 0):
+        spec[1] = "model"
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*spec))
 
 
 # ---------------------------------------------------------------------------
@@ -148,18 +174,21 @@ def gqa_project_qkv(p, x, n_heads: int, n_kv: int, d_head: int,
 
 
 def attend(q, k, v, causal: bool = True, q_offset: int = 0,
-           kv_len_mask=None):
-    """softmax(q·kᵀ)·v with GQA head grouping. q: (B,Hq,Sq,dh), k/v (B,Hkv,Skv,dh).
+           kv_len_mask=None, scale: float | None = None):
+    """softmax(scale·q·kᵀ)·v with GQA head grouping. q: (B,Hq,Sq,dh), k/v
+    (B,Hkv,Skv,dh).
 
     ``q_offset``: absolute position of q[...,0,:] (decode: Skv-1).
     ``kv_len_mask``: optional (B, Skv) validity mask for ragged caches.
+    ``scale``: the score scale, 1/sqrt(dh) when None (every ``attend*``).
     """
     b, hq, sq, dh = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     group = hq // hkv
+    scale = dh ** -0.5 if scale is None else scale
     qg = q.reshape(b, hkv, group, sq, dh)
     logits = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * (dh ** -0.5)
+                        k.astype(jnp.float32)) * scale
     if causal and sq > 1:
         qpos = q_offset + jnp.arange(sq)
         kpos = jnp.arange(skv)
@@ -173,7 +202,8 @@ def attend(q, k, v, causal: bool = True, q_offset: int = 0,
 
 
 def attend_flash(q, k, v, chunk: int = 1024, q_offset: int = 0,
-                 causal: bool = True, bf16_scores: bool = False):
+                 causal: bool = True, bf16_scores: bool = False,
+                 scale: float | None = None):
     """Online-softmax blocked attention (jnp twin of kernels/flash_attention).
 
     Unrolled q/kv chunk loops: strictly-future blocks are *not emitted*, so
@@ -186,9 +216,10 @@ def attend_flash(q, k, v, chunk: int = 1024, q_offset: int = 0,
     b, hq, sq, dh = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     group = hq // hkv
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     if sq % chunk or skv % chunk:
-        return attend(q, k, v, causal=causal, q_offset=q_offset)
+        return attend(q, k, v, causal=causal, q_offset=q_offset,
+                      scale=scale)
     sdt = jnp.bfloat16 if bf16_scores else jnp.float32
     qg = q.reshape(b, hkv, group, sq, dh)
     outs = []
@@ -231,7 +262,7 @@ def auto_chunk(seq_len: int) -> int:
 
 
 def attend_flash_scan(q, k, v, chunk: int = 1024, q_offset: int = 0,
-                      causal: bool = True):
+                      causal: bool = True, scale: float | None = None):
     """attend_flash with the kv loop as a ``lax.scan``: identical math,
     but the compiled program provably reuses one block of buffers per
     step — the memory model the dry-run reports (the unrolled twin is
@@ -240,9 +271,10 @@ def attend_flash_scan(q, k, v, chunk: int = 1024, q_offset: int = 0,
     b, hq, sq, dh = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     group = hq // hkv
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     if sq % chunk or skv % chunk:
-        return attend(q, k, v, causal=causal, q_offset=q_offset)
+        return attend(q, k, v, causal=causal, q_offset=q_offset,
+                      scale=scale)
     qg = q.reshape(b, hkv, group, sq, dh)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -279,14 +311,15 @@ def attend_flash_scan(q, k, v, chunk: int = 1024, q_offset: int = 0,
     return out.reshape(b, hq, sq, v.shape[-1]).astype(q.dtype)
 
 
-def attend_chunked(q, k, v, chunk: int = 2048, q_offset: int = 0):
+def attend_chunked(q, k, v, chunk: int = 2048, q_offset: int = 0,
+                   scale: float | None = None):
     """Causal attention computed per q-chunk against only the kv prefix it
     can see — skips strictly-future kv, halving score FLOPs vs the dense
     mask (beyond-paper §Perf optimisation; the Pallas flash kernel is the
     TPU-runtime twin of this HLO-level schedule)."""
     b, hq, sq, dh = q.shape
     if sq <= chunk:
-        return attend(q, k, v, causal=True, q_offset=q_offset)
+        return attend(q, k, v, causal=True, q_offset=q_offset, scale=scale)
     assert sq % chunk == 0
     outs = []
     for c in range(sq // chunk):
@@ -294,7 +327,7 @@ def attend_chunked(q, k, v, chunk: int = 2048, q_offset: int = 0):
         kv_hi = q_offset + lo + chunk
         outs.append(attend(q[:, :, lo:lo + chunk], k[:, :, :kv_hi],
                            v[:, :, :kv_hi], causal=True,
-                           q_offset=q_offset + lo))
+                           q_offset=q_offset + lo, scale=scale))
     return jnp.concatenate(outs, axis=2)
 
 

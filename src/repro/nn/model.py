@@ -70,14 +70,52 @@ class LM:
         cfg = self.cfg
         s = q.shape[2]
         chunk = cfg.attn_chunk or L.auto_chunk(s)
+        scale = self._attn_scale(q.shape[-1])
         if cfg.attn_impl == "flash":
             if cfg.flash_impl == "scan":
-                return L.attend_flash_scan(q, k, v, chunk=min(chunk, s))
+                return L.attend_flash_scan(q, k, v, chunk=min(chunk, s),
+                                           scale=scale)
             return L.attend_flash(q, k, v, chunk=min(chunk, s),
-                                  bf16_scores=cfg.attn_bf16_scores)
+                                  bf16_scores=cfg.attn_bf16_scores,
+                                  scale=scale)
         if cfg.attn_impl == "chunked":
-            return L.attend_chunked(q, k, v, chunk=min(chunk, s))
-        return L.attend(q, k, v, causal=True)
+            return L.attend_chunked(q, k, v, chunk=min(chunk, s),
+                                    scale=scale)
+        return L.attend(q, k, v, causal=True, scale=scale)
+
+    # granite's scalings; at the neutral defaults each is the plain
+    # operation, with nothing added to the program
+    def _attn_scale(self, dim: int) -> float:
+        """Attention score scale: ``attention_multiplier``, else
+        1/sqrt(dim)."""
+        m = self.cfg.attention_multiplier
+        return dim ** -0.5 if m is None else m
+
+    def _residual(self, x, out):
+        """``x`` plus a sub-block's ``out`` times ``residual_multiplier``,
+        taken in float32 and rounded once to ``x``'s dtype: a multiplier
+        such as 0.22 has no bfloat16 value (it rounds to 0.2197), and a
+        bfloat16 product would shrink every block's output by that much."""
+        r = self.cfg.residual_multiplier
+        if r == 1.0:
+            return x + out
+        return (x + out.astype(jnp.float32) * r).astype(x.dtype)
+
+    def _norm(self, p, x):
+        """The block norm: RMSNorm at ``norm_eps``, or LayerNorm."""
+        if self.cfg.norm == "rmsnorm":
+            return L.rmsnorm(p, x, self.cfg.norm_eps)
+        return L.layernorm(p, x)
+
+    def _embed(self, params, batch) -> jax.Array:
+        """Token embeddings (or stub-frontend embeds) times
+        ``embedding_multiplier``, in the compute dtype."""
+        x = (batch["embeds"] if "embeds" in batch
+             else params["embed"][batch["tokens"]])
+        m = self.cfg.embedding_multiplier
+        if m != 1.0:
+            x = x.astype(jnp.float32) * m
+        return x.astype(L.COMPUTE_DTYPE)
 
     # ------------------------------------------------------------------ init
     def _init_block(self, key) -> dict:
@@ -165,10 +203,7 @@ class LM:
     @jax.named_scope("lm.embed")
     def embed_inputs(self, params, batch) -> jax.Array:
         """tokens (B,S) → (B,S,d), or pass through stub-frontend embeds."""
-        if "embeds" in batch:
-            x = batch["embeds"].astype(L.COMPUTE_DTYPE)
-        else:
-            x = params["embed"][batch["tokens"]].astype(L.COMPUTE_DTYPE)
+        x = self._embed(params, batch)
         if self.cfg.family == "audio" and not self.cfg.rope:
             b, s, d = x.shape
             pos = self._sinusoid(s, d, offset=0)
@@ -184,11 +219,21 @@ class LM:
 
     @jax.named_scope("lm.head")
     def unembed(self, params, x) -> jax.Array:
-        norm = (L.rmsnorm if self.cfg.norm == "rmsnorm" else L.layernorm)
-        x = norm(params["final_norm"], x)
+        x = self._head_input(params, x)
         head = (params["embed"].T if self.cfg.tie_embeddings
                 else params["lm_head"])
         return jnp.dot(x, head.astype(x.dtype))
+
+    def _head_input(self, params, x):
+        """The final norm, over ``logits_scaling`` (in float32, rounded
+        once): the head is linear, so this divides the logits (exactly,
+        for a power of two) at a twelfth of the cost for a 49k vocabulary,
+        and with no logits-sized buffer in the backward pass."""
+        x = self._norm(params["final_norm"], x)
+        ls = self.cfg.logits_scaling
+        if ls == 1.0:
+            return x
+        return (x.astype(jnp.float32) / ls).astype(x.dtype)
 
     # ------------------------------------------------------ layer-stack body
     @jax.named_scope("lm.attention")
@@ -215,24 +260,25 @@ class LM:
         valid = (jnp.arange(ck.shape[2]) <= pos)[None]
         o = L.attend(q, ck.astype(q.dtype), cv.astype(q.dtype), causal=False,
                      kv_len_mask=jnp.broadcast_to(valid, (x.shape[0],
-                                                          ck.shape[2])))
+                                                          ck.shape[2])),
+                     scale=self._attn_scale(cfg.d_head))
         return L.merge_heads(o) @ L.cdt(p["wo"]), (ck, cv)
 
     def _block(self, p, x, cos, sin, cache=None, pos=None):
         """One transformer block. Returns (x, aux_loss, new_cache)."""
         cfg = self.cfg
-        norm = L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+        norm = self._norm
         aux = jnp.zeros((), jnp.float32)
         if cfg.family == "ssm":
             o, st_t = S.rwkv6_time_mix(
                 p["tmix"], norm(p["norm1"], x),
                 cfg.d_model // cfg.ssm.head_dim,
                 state=None if cache is None else cache[0])
-            x = x + o
+            x = self._residual(x, o)
             o, st_c = S.rwkv6_channel_mix(
                 p["cmix"], norm(p["norm2"], x),
                 state=None if cache is None else cache[1])
-            x = x + o
+            x = self._residual(x, o)
             return x, aux, (st_t, st_c)
         if cfg.family == "hybrid":
             dims = (cfg.ssm.expand * cfg.d_model, cfg.ssm.head_dim,
@@ -243,10 +289,10 @@ class LM:
                                    compute_dtype=(jnp.bfloat16
                                                   if cfg.ssm_bf16
                                                   else jnp.float32))
-            return x + o, aux, st
+            return self._residual(x, o), aux, st
         attn_out, kv = self._attn_block(p["attn"], norm(p["norm1"], x),
                                         cos, sin, cache=cache, pos=pos)
-        x = x + attn_out
+        x = self._residual(x, attn_out)
         h = norm(p["norm2"], x)
         with jax.named_scope("lm.mlp"):
             if "moe" in p:
@@ -257,7 +303,7 @@ class LM:
             else:
                 out = (L.swiglu(p["mlp"], h) if cfg.mlp == "swiglu"
                        else L.gelu_mlp(p["mlp"], h))
-        return x + out, aux, kv
+        return self._residual(x, out), aux, kv
 
     def _mla_block_decode(self, p, x, cos, sin, cache, pos):
         """Absorbed-matmul MLA decode: attend in the compressed latent space.
@@ -290,7 +336,7 @@ class LM:
                   jnp.einsum("bhr,bsr->bhs",
                              q_rope[:, :, 0].astype(jnp.float32),
                              krope.astype(jnp.float32)))
-        logits = logits * ((m.d_nope + m.d_rope) ** -0.5)
+        logits = logits * self._attn_scale(m.d_nope + m.d_rope)
         valid = (jnp.arange(ckv.shape[1]) <= pos)[None, None]
         logits = jnp.where(valid, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
@@ -306,7 +352,7 @@ class LM:
 
         def body(carry, lp):
             xx, aux = carry
-            out, a, _ = self._block(lp, xx, cos, sin)
+            out, a, _ = self._block(lp, L.split_tokens(xx), cos, sin)
             return (out, aux + a), None
 
         if cfg.remat == "full":
@@ -323,7 +369,7 @@ class LM:
         """Full-sequence forward up to (but excluding) the LM head.
         Returns (hidden (B,S,d), aux_loss)."""
         cfg = self.cfg
-        x = self.embed_inputs(params, batch)
+        x = L.split_tokens(self.embed_inputs(params, batch))
         s = x.shape[1]
         cos, sin = (L.rope_table(s, self._rope_dim(), cfg.rope_theta)
                     if cfg.rope else (None, None))
@@ -374,14 +420,14 @@ class LM:
         """Zamba2 shared block: concat(hidden, embeddings) → 2d→d proj →
         attn + MLP, residual back into the Mamba stream."""
         h = jnp.concatenate([x, x0], axis=-1) @ L.cdt(p["in_proj"])
-        a_in = L.rmsnorm(p["norm1"], h)
+        a_in = self._norm(p["norm1"], h)
         attn_out, kv = self._attn_block(p["attn"], a_in, cos, sin,
                                         cache=cache, pos=pos)
-        h = h + attn_out
-        m_in = L.rmsnorm(p["norm2"], h)
+        h = self._residual(h, attn_out)
+        m_in = self._norm(p["norm2"], h)
         with jax.named_scope("lm.mlp"):
             m_out = L.swiglu(p["mlp"], m_in)
-        return x + (h + m_out), kv
+        return x + self._residual(h, m_out), kv
 
     def _rope_dim(self):
         return (self.cfg.mla.d_rope if self.cfg.mla is not None
@@ -389,9 +435,14 @@ class LM:
 
     # ------------------------------------------------------------- training
     def loss_fn(self, params, batch):
+        """Mean next-token cross-entropy (+ 0.01 × the MoE balance loss).
+        Under a mesh the head and loss take the positions split over
+        ``model`` (``L.split_tokens``) as well as the batch over the data
+        axes."""
         if self.cfg.loss_impl == "chunked":
             return self._loss_chunked(params, batch)
-        logits, aux = self.forward(params, batch)
+        x, aux = self.backbone(params, batch)
+        logits = self.unembed(params, L.split_tokens(x, over_model=True))
         labels = batch["labels"]
         with jax.named_scope("lm.loss"):
             logits = logits.astype(jnp.float32)
@@ -416,9 +467,9 @@ class LM:
         vocab chunks (beyond-paper memory optimisation, §Perf)."""
         cfg = self.cfg
         x, aux = self.backbone(params, batch)
-        norm = (L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm)
+        x = L.split_tokens(x, over_model=True)
         with jax.named_scope("lm.head"):
-            xn = norm(params["final_norm"], x)
+            xn = self._head_input(params, x)
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
         labels = batch["labels"]
@@ -551,11 +602,11 @@ class LM:
 
     def _mla_block_and_ffn(self, p, x, cos, sin, cache, pos, dense):
         cfg = self.cfg
-        norm = L.rmsnorm if cfg.norm == "rmsnorm" else L.layernorm
+        norm = self._norm
         with jax.named_scope("lm.attention"):
             o, new_cache = self._mla_block_decode(p, norm(p["norm1"], x),
                                                   cos, sin, cache, pos)
-        x = x + o
+        x = self._residual(x, o)
         h = norm(p["norm2"], x)
         with jax.named_scope("lm.mlp"):
             if dense or "mlp" in p:
@@ -565,7 +616,7 @@ class LM:
                 out, _ = M.moe_ffn(p["moe"], h.reshape(b * s, d),
                                    _moe_cfg(cfg))
                 out = out.reshape(b, s, d)
-        return x + out, new_cache
+        return self._residual(x, out), new_cache
 
     def _decode_hybrid(self, params, x, cache, pos, cos, sin):
         cfg = self.cfg
@@ -602,10 +653,7 @@ class LM:
 
     @jax.named_scope("lm.embed")
     def embed_inputs_decode(self, params, batch, pos):
-        if "embeds" in batch:
-            x = batch["embeds"].astype(L.COMPUTE_DTYPE)
-        else:
-            x = params["embed"][batch["tokens"]].astype(L.COMPUTE_DTYPE)
+        x = self._embed(params, batch)
         if self.cfg.family == "audio" and not self.cfg.rope:
             d = x.shape[-1]
             pos_f = jnp.arange(0, d, 2, dtype=jnp.float32)
@@ -642,7 +690,7 @@ class LM:
         if cfg.mla is not None:
             def body(carry, lp):
                 xx = carry
-                norm = L.rmsnorm
+                norm = self._norm
                 h = norm(lp["norm1"], xx)
                 with jax.named_scope("lm.attention"):
                     q, k, v, c_kv = L.mla_qkv(lp["attn"], h, cfg.n_heads,
@@ -650,7 +698,7 @@ class LM:
                                               cfg.mla.d_v, cos, sin)
                     o = self._attend_full(q, k, v)
                     o = L.merge_heads(o) @ L.cdt(lp["attn"]["wo"])
-                xx = xx + o
+                xx = self._residual(xx, o)
                 hh = norm(lp["norm2"], xx)
                 with jax.named_scope("lm.mlp"):
                     if "moe" in lp:
@@ -661,7 +709,7 @@ class LM:
                         out = out.reshape(bb, ss, dd)
                     else:
                         out = L.swiglu(lp["mlp"], hh)
-                xx = xx + out
+                xx = self._residual(xx, out)
                 with jax.named_scope("lm.attention"):
                     k_rope = jnp.dot(h, L.cdt(lp["attn"]["wk_rope"]))
                     k_rope = L.apply_rope(k_rope[:, None], cos, sin)[:, 0]

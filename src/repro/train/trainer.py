@@ -3,7 +3,9 @@ checkpoint/restart, straggler monitoring.
 
 ``make_train_step`` builds the pure step function the dry-run lowers; the
 ``Trainer`` class wraps it with the operational substrate (fault tolerance,
-checkpoint cadence, metrics) for the runnable examples.
+checkpoint cadence, metrics) for the runnable examples, on one device or,
+given a ``mesh``, with its state and batches sharded over the mesh by the
+rules of ``launch/sharding.py``.
 """
 from __future__ import annotations
 
@@ -15,7 +17,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.launch.sharding import (batch_shardings, opt_shardings,
+                                   param_shardings)
 from repro.obs import tracer_of
+from repro.obs.hlo import collectives
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
 
 
@@ -93,19 +98,70 @@ class StragglerMonitor:
         return False
 
 
+class MeshStep:
+    """The train step jitted for a mesh, compiled ahead of its first call
+    for each signature of its inputs. Each compile adds what one step's
+    program moves between chips (``obs.hlo.collectives``, per chip) to the
+    counters ``train.collectives.<kind>`` and
+    ``train.collective_bytes.<kind>`` of the active tracer."""
+
+    def __init__(self, jitted, owner):
+        self.jitted, self.owner = jitted, owner
+        self._compiled: dict = {}
+
+    def lower(self, *args):
+        """The step traced under the mesh (``jax.set_mesh``), so that the
+        model lays its activations out on it (``nn.layers.split_tokens``)."""
+        with jax.set_mesh(self.owner.mesh):
+            return self.jitted.lower(*args)
+
+    def __call__(self, *args):
+        sig = tuple((x.shape, x.dtype, getattr(x, "sharding", None))
+                    for x in jax.tree.leaves(args))
+        fn = self._compiled.get(sig)
+        if fn is None:
+            fn = self._compiled[sig] = self.lower(*args).compile()
+            tr = tracer_of(self.owner)
+            for kind, c in collectives(fn.as_text()).items():
+                tr.inc(f"train.collectives.{kind}", c["count"])
+                tr.inc(f"train.collective_bytes.{kind}", c["bytes"])
+        return fn(*args)
+
+
 class Trainer:
-    """Checkpointed, straggler-aware training driver."""
+    """Checkpointed, straggler-aware training driver.
+
+    With a ``mesh`` (axes ``data`` and ``model``) parameters and optimizer
+    state live on it in the shardings of ``launch/sharding.py``
+    (``param_shardings``, ``opt_shardings``: tensor-parallel over
+    ``model``, FSDP and ZeRO over ``data``), batches are placed by
+    ``batch_shardings``, and ``step_fn`` is a :class:`MeshStep`."""
 
     def __init__(self, model, optimizer: Optimizer, data,
                  checkpoint_dir: Optional[str] = None,
                  checkpoint_every: int = 50, grad_accum: int = 1,
-                 clip_norm: float = 1.0, donate: bool = True):
+                 clip_norm: float = 1.0, donate: bool = True, mesh=None):
         self.model = model
         self.optimizer = optimizer
         self.data = data
-        self.step_fn = jax.jit(
-            make_train_step(model.loss_fn, optimizer, grad_accum, clip_norm),
-            donate_argnums=(0, 1) if donate else ())
+        self.mesh = mesh
+        step = make_train_step(model.loss_fn, optimizer, grad_accum,
+                               clip_norm)
+        donate_argnums = (0, 1) if donate else ()
+        if mesh is None:
+            self.step_fn = jax.jit(step, donate_argnums=donate_argnums)
+        else:
+            p_shapes = jax.eval_shape(
+                model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+            self.param_sharding = param_shardings(p_shapes, mesh)
+            self.opt_sharding = opt_shardings(
+                jax.eval_shape(optimizer.init, p_shapes),
+                self.param_sharding, mesh)
+            state = (self.param_sharding, self.opt_sharding)
+            self.step_fn = MeshStep(jax.jit(
+                step, in_shardings=state + (None,),
+                out_shardings=state + (None,),
+                donate_argnums=donate_argnums), self)
         self.ckpt = (Checkpointer(checkpoint_dir)
                      if checkpoint_dir else None)
         self.checkpoint_every = checkpoint_every
@@ -113,8 +169,29 @@ class Trainer:
         self.history: list[dict] = []
 
     def init_state(self, key):
-        params = self.model.init(key)
-        return params, self.optimizer.init(params)
+        """The model's parameters and the optimizer's state for them. With
+        a mesh both are made on it, each leaf in its own sharding and
+        never whole on one device, under the span ``train.place``."""
+        if self.mesh is None:
+            params = self.model.init(key)
+            return params, self.optimizer.init(params)
+
+        def make(k):
+            params = self.model.init(k)
+            return params, self.optimizer.init(params)
+
+        with tracer_of(self).span("train.place"):
+            state = jax.jit(make, out_shardings=(
+                self.param_sharding, self.opt_sharding))(key)
+            return jax.block_until_ready(state)
+
+    def place_batch(self, batch: dict) -> dict:
+        """``batch`` on the mesh by ``batch_shardings`` (as it is without
+        a mesh)."""
+        if self.mesh is None:
+            return batch
+        rows = jax.tree.leaves(batch)[0].shape[0]
+        return jax.device_put(batch, batch_shardings(batch, self.mesh, rows))
 
     def restore_or_init(self, key):
         """Crash-restart entry point: resume from the latest checkpoint if
@@ -124,7 +201,8 @@ class Trainer:
         start = 0
         if self.ckpt and self.ckpt.latest_step() is not None:
             (params, opt_state), start = self.ckpt.restore(
-                (params, opt_state))
+                (params, opt_state), sharding_tree=None if self.mesh is None
+                else (self.param_sharding, self.opt_sharding))
         return params, opt_state, start
 
     def run(self, key, n_steps: int, log_every: int = 10,
@@ -141,7 +219,7 @@ class Trainer:
         for step in range(start, n_steps):
             with jax.profiler.StepTraceAnnotation("train", step_num=step):
                 with tr.span("train.batch", step=step):
-                    batch = self.data.batch_at(step)
+                    batch = self.place_batch(self.data.batch_at(step))
                 t0 = time.perf_counter()
                 with tr.span("train.step", step=step):
                     params, opt_state, metrics = self.step_fn(

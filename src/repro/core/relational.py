@@ -10,12 +10,17 @@ matrix algebra onto relational algebra (Listing 4):
 
 TPU adaptation (DESIGN.md §2): the database's hash join + hash aggregation has
 no analogue in VMEM, so we execute the join as a *sort-merge join over the
-canonically sorted relation* (a gather of the matching inner tuples) and the
-group-by as a *segment sum* over the sorted outer index — the sort-based
-aggregation with continuous output that the paper's §8 proposes as future
-work. The join intermediate (``nnz(A) × n`` tuples before aggregation — the
-thousandfold blow-up of Fig. 5) is explicit in this formulation and is what
-`benchmarks/fig5_matmul_memory.py` measures.
+canonically sorted relation*. For a canonical left operand (one tuple per
+cell, row-major) the sort order is fixed by the shape: the tuples come in
+runs of ``k`` per outer index ``i`` with ``j`` = 0…k−1, so the join is the
+identity alignment against the inner relation's clusters and the group-by
+is an *ordered reduction over each fixed run* — the sort-based aggregation
+with continuous output that the paper's §8 proposes as future work. A left
+operand with fewer tuples than cells (sparse or padded) has no fixed runs:
+its join gathers the matching inner tuples and its group-by is a *segment
+sum* (a scatter-add) over ``i``. The join intermediate (``nnz(A) × n``
+tuples before aggregation — the thousandfold blow-up of Fig. 5) is explicit
+in this formulation and is what `benchmarks/fig5_matmul_memory.py` measures.
 
 Matrices are stored *densely* in the relation (no CSR — §6.2.2 of the paper),
 in canonical row-major order. A ``RelTensor`` may also carry fewer valid
@@ -23,9 +28,11 @@ tuples than its capacity (``nnz``) for genuinely sparse relations such as the
 one-hot matrix or the MoE token→expert assignment; padding rows carry an
 out-of-range ``i`` so the group-by drops them (scatter-drop semantics).
 
-The device ops of each step carry a named scope: ``rel.join`` (the gather-
-multiply), ``rel.group_by`` (the segment sum) and ``rel.pivot`` (dense ↔
-relation), so a profiler trace charges the chip's time to them.
+The device ops of each step carry a named scope: ``rel.join`` (the
+multiply, with its gather on the sparse path), ``rel.group_by`` (the run
+reduction or the segment sum) and ``rel.pivot`` (dense ↔ relation), so a
+profiler trace charges the chip's time to them. Each product traced counts
+``rel.group_by.ordered`` or ``rel.group_by.scatter`` on the active tracer.
 """
 from __future__ import annotations
 
@@ -34,6 +41,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from ..obs.tracer import current
 
 
 def _canonical_indices(m: int, n: int) -> tuple[jax.Array, jax.Array]:
@@ -68,9 +77,13 @@ class RelTensor:
 
     def to_dense(self) -> jax.Array:
         """Materialise the relation as a dense matrix (outer-join + coalesce:
-        missing cells become 0, as in Listing 5's one-hot construction)."""
+        missing cells become 0, as in Listing 5's one-hot construction).
+        A canonical relation holds every cell in row-major order, so its
+        values are the matrix already; a sparse or padded one scatters."""
         m, n = self.shape
         with jax.named_scope("rel.pivot"):
+            if self.is_canonical():
+                return self.v.reshape(m, n)
             out = jnp.zeros((m, n), dtype=self.v.dtype)
             return out.at[self.i, self.j].add(self.v, mode="drop")
 
@@ -130,13 +143,22 @@ class RelTensor:
     def matmul(self, other: "RelTensor") -> "RelTensor":
         """γ_{m.i, n.j, sum(m.v·n.v)}(m ⋈_{m.j = n.i} n).
 
-        1. JOIN  — for each tuple ``(i, k, v)`` of ``self`` gather the ``n``
+        1. JOIN  — each tuple ``(i, k, v)`` of ``self`` meets the ``n``
            tuples of ``other`` with inner index ``k`` (sort-merge join against
            the canonical order). The joined intermediate has
            ``capacity(self) × n`` tuples — the paper's Fig. 5 blow-up.
-        2. GROUP BY (m.i, n.j) with sum — a segment sum over the sorted outer
-           row index. Padding tuples (``i == m``) are dropped (scatter-drop),
-           mirroring the inner join discarding non-matching tuples.
+        2. GROUP BY (m.i, n.j) with sum.
+
+        A canonical ``self`` (``is_canonical()``) is sorted by ``i`` in runs
+        of exactly ``k`` tuples with ``j`` = 0…k−1, so tuple ``(i, kk)``
+        meets inner cluster ``kk`` without a gather, and the group-by is a
+        reduction over each run: an ``(m, k, n)`` broadcast multiply summed
+        over ``k``, which XLA fuses into one loop without materialising the
+        join. A ``self`` with fewer tuples than cells (the sparse one-hot,
+        the MoE assignment, padded relations) gathers the inner clusters by
+        ``j`` and groups by a segment sum over ``i`` (a scatter-add); its
+        padding tuples (``i == m``) are dropped (scatter-drop), mirroring
+        the inner join discarding non-matching tuples.
         """
         if self.shape[1] != other.shape[0]:
             raise ValueError(f"matmul: {self.shape} @ {other.shape}")
@@ -144,11 +166,19 @@ class RelTensor:
             raise ValueError("rhs of the join must be the canonical relation")
         m, k = self.shape
         n = other.shape[1]
-        with jax.named_scope("rel.join"):
-            rhs_rows = other.v.reshape(k, n)     # clustered by inner index
-            joined = self.v[:, None] * rhs_rows[self.j]    # (cap, n) joined
-        with jax.named_scope("rel.group_by"):
-            out = jax.ops.segment_sum(joined, self.i, num_segments=m)
+        rhs_rows = other.v.reshape(k, n)     # clustered by inner index
+        if self.is_canonical():
+            current().inc("rel.group_by.ordered")
+            with jax.named_scope("rel.join"):
+                joined = self.v.reshape(m, k, 1) * rhs_rows    # (m, k, n)
+            with jax.named_scope("rel.group_by"):
+                out = joined.sum(axis=1)
+        else:
+            current().inc("rel.group_by.scatter")
+            with jax.named_scope("rel.join"):
+                joined = self.v[:, None] * rhs_rows[self.j]    # (cap, n)
+            with jax.named_scope("rel.group_by"):
+                out = jax.ops.segment_sum(joined, self.i, num_segments=m)
         return RelTensor.from_dense(out)
 
     def matmul_intermediate_tuples(self, other: "RelTensor") -> int:

@@ -11,6 +11,7 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis "
 import hypothesis.strategies as st
 from hypothesis import given, settings
 
+from repro import obs
 from repro.core import Engine, autodiff, dense, nn2sql
 from repro.core import expr as E
 from repro.core.recursive_cte import history_bytes, recursive_cte
@@ -168,6 +169,69 @@ class TestRelTensor:
         np.testing.assert_array_equal(t.to_dense(), expect.T)
         np.testing.assert_array_equal(t.transpose().to_dense(), expect)
 
+    # the group-by as a scatter: the gather-join and segment sum that a
+    # canonical left operand's ordered run reduction must agree with
+    @staticmethod
+    def scatter_matmul(rel, other):
+        m, k = rel.shape
+        joined = rel.v[:, None] * other.v.reshape(k, other.shape[1])[rel.j]
+        return RelTensor.from_dense(
+            jax.ops.segment_sum(joined, rel.i, num_segments=m))
+
+    @staticmethod
+    def assert_f32_product(got, a, b, want):
+        """|got − want| within float32 rounding of the sum of the products'
+        magnitudes, ``rtol=1e-5`` of ``|a| @ |b|`` per cell."""
+        a64, b64 = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        err = np.abs(np.asarray(got, np.float64) - np.asarray(want,
+                                                             np.float64))
+        assert (err <= 1e-5 * (np.abs(a64) @ np.abs(b64))).all()
+
+    # single cells, a row, a column and a one-wide product, and the
+    # relational cell's products at CPU sizes (n = 10: the output layer)
+    PRODUCTS = [(1, 1, 1), (1, 9, 4), (6, 1, 5), (6, 9, 1), (7, 5, 3),
+                (64, 96, 130), (200, 784, 10)]
+
+    @pytest.mark.parametrize("m,k,n", PRODUCTS)
+    def test_canonical_matmul_is_the_scatter_construction(self, m, k, n):
+        a, b = rnd(m, k), rnd(k, n)
+        ra, rb = RelTensor.from_dense(a), RelTensor.from_dense(b)
+        want64 = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+        for got, want in [(ra.matmul(rb), self.scatter_matmul(ra, rb)),
+                          (jax.jit(RelTensor.matmul)(ra, rb),
+                           jax.jit(self.scatter_matmul)(ra, rb))]:
+            assert got.shape == (m, n) and got.is_canonical()
+            np.testing.assert_array_equal(got.i, want.i)
+            np.testing.assert_array_equal(got.j, want.j)
+            self.assert_f32_product(got.v.reshape(m, n), a, b,
+                                    want.v.reshape(m, n))
+            self.assert_f32_product(got.v.reshape(m, n), a, b, want64)
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_canonical_to_dense_is_the_scatter(self, shape):
+        rel = RelTensor.from_dense(rnd(*shape))
+        scattered = jnp.zeros(shape).at[rel.i, rel.j].add(rel.v)
+        for got in (rel.to_dense(), jax.jit(RelTensor.to_dense)(rel)):
+            np.testing.assert_array_equal(got, scattered)
+
+    def test_group_by_counts_its_path(self):
+        """A canonical left operand takes the ordered run reduction; the
+        sparse one-hot and a padded relation take the scatter."""
+        b = RelTensor.from_dense(rnd(8, 5))
+        padded = RelTensor(i=jnp.array([0, 2, 3, 4, 4], jnp.int32),
+                           j=jnp.array([1, 0, 7, 0, 0], jnp.int32),
+                           v=rnd(5), shape=(4, 8))
+        sparse = one_hot(jnp.array([3, 0, 7], jnp.int32), 8)
+        for rel, path in [(RelTensor.from_dense(rnd(4, 8)), "ordered"),
+                          (padded, "scatter"), (sparse, "scatter")]:
+            tracer = obs.Tracer()
+            with obs.use(tracer):
+                out = rel.matmul(b)
+            assert tracer.counters == {f"rel.group_by.{path}": 1}
+            self.assert_f32_product(out.to_dense(), rel.to_dense(),
+                                    b.to_dense(),
+                                    rel.to_dense() @ b.to_dense())
+
 
 # ---------------------------------------------------------------------------
 # Algorithm 1 (reverse-mode AD over matrix expressions)
@@ -293,6 +357,37 @@ class TestEngines:
         want = sorting(w0, x, y)
         for k in ("w_xh", "w_ho"):
             np.testing.assert_array_equal(got[k], want[k])
+
+    def test_relational_training_query_scatters_nothing(self, monkeypatch):
+        """Every product of the training graph has a canonical left operand:
+        tracing the query counts five ordered group-bys (two forward, three
+        backward) and no scatter, the compiled query holds no scatter, and
+        its weights are those of the same query grouping by segment sum,
+        within float32 rounding."""
+        spec = nn2sql.MLPSpec(40, 24, 12, 10)
+        g = nn2sql.build_graph(spec)
+        r = np.random.RandomState(6)
+        x = jnp.asarray(r.rand(40, 24), jnp.float32)
+        y = jnp.asarray(jax.nn.one_hot(r.randint(0, 10, 40), 10))
+        w0 = nn2sql.init_weights(spec)
+        eng = Engine("relational")
+
+        def query():
+            return jax.jit(
+                lambda w, x, y: nn2sql.train(g, w, x, y, 4, eng)[0])
+
+        tracer = obs.Tracer()
+        with obs.use(tracer):
+            compiled = query().lower(w0, x, y).compile()
+        assert tracer.counters == {"rel.group_by.ordered": 5}
+        assert not re.search(r"\bscatter\(", compiled.as_text())
+        got = compiled(w0, x, y)
+        monkeypatch.setattr(RelTensor, "matmul", TestRelTensor.scatter_matmul)
+        scattering = query().lower(w0, x, y).compile()
+        assert re.search(r"\bscatter\(", scattering.as_text())
+        want = scattering(w0, x, y)
+        for k in ("w_xh", "w_ho"):
+            np.testing.assert_allclose(got[k], want[k], rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,8 @@ topology is described inside a fixture, never at import time, so every
 test worker collects the same tests and only the worker given this file
 loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -111,6 +113,28 @@ def test_dense_mlp_train_step_compiles_for_v5e(one_chip):
         w, x, y)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 ** 30
+
+
+def test_relational_mlp_query_groups_without_a_scatter(one_chip):
+    """A 10-iteration training query of the paper's network on the
+    relational engine: every product's group-by is a reduction over the
+    fixed runs of a canonical relation, so the program holds no scatter,
+    keeps no joined tuples in memory (1.25 GB for the 2000×784·784×200
+    product), and rounds no operand of a product to bfloat16."""
+    from repro.core import nn2sql
+    from repro.core.engine import Engine
+
+    g = nn2sql.build_graph(nn2sql.MLPSpec(2000, 784, 200, 10))
+    s = _spec(one_chip)
+    w = {"w_xh": s((784, 200)), "w_ho": s((200, 10))}
+    compiled = _compile(
+        lambda w, x, y: nn2sql.train(g, w, x, y, 10, Engine("relational"))[0],
+        w, s((2000, 784)), s((2000, 10)))
+    text = compiled.as_text()
+    assert not re.search(r"\bscatter\(", text)
+    for op in re.findall(r"\b(?:dot|convolution)\(.*", text):
+        assert "operand_precision={highest,highest}" in op
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
 
 
 def test_granite_train_step_fits_a_2x2_mesh(topo):

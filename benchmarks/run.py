@@ -1,12 +1,9 @@
 """Benchmark harness — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV. Also emits the roofline summary
-from the dry-run results file when present (results/dryrun_baseline.json).
+Prints ``name,us_per_call,derived`` CSV.
 """
 from __future__ import annotations
 
-import json
-import os
 import sys
 
 from . import paper_figures as F
@@ -28,20 +25,6 @@ def main() -> None:
         for r in suite():
             print(f"{r['name']},{r['us_per_call']:.1f},\"{r['derived']}\"")
             sys.stdout.flush()
-    # roofline summary appendix (from the dry-run, if it has been run)
-    path = os.path.join(os.path.dirname(__file__), "..", "results",
-                        "dryrun_baseline.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            recs = json.load(f)
-        for r in recs:
-            if r.get("status") != "ok" or r.get("mesh") != "16x16":
-                continue
-            t = r["terms_s"]
-            step = max(t.values())
-            print(f"roofline/{r['arch']}_{r['shape']},{step * 1e6:.1f},"
-                  f"\"bottleneck={r['bottleneck']} "
-                  f"frac={r.get('roofline_fraction', 0):.3f}\"")
 
 
 if __name__ == "__main__":

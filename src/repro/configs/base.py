@@ -64,7 +64,6 @@ class ArchConfig:
     ssm: Optional[SSMSpec] = None
     stub_frontend: Optional[str] = None   # "audio_frames" | "vision_patches"
     shared_attn_every: int = 0            # zamba2: shared block period
-    sub_quadratic: bool = False           # may run long_500k
     # granite's scalings (neutral defaults add no operation): the token
     # embedding times ``embedding_multiplier``; attention scores times
     # ``attention_multiplier`` (None: 1/sqrt(d_head)); each block's
@@ -75,16 +74,10 @@ class ArchConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     norm_eps: float = 1e-6                # RMSNorm epsilon
-    # execution knobs (hillclimbed in §Perf)
-    attn_impl: str = "flash"              # flash | chunked | dense
-    attn_chunk: int = 0                   # 0 = auto
+    # execution knobs
     remat: str = "full"                   # none | full | dots
-    scan_layers: bool = True
-    ssm_bf16: bool = False                # SSD chunk math in bf16 (§Perf)
+    ssm_bf16: bool = False                # SSD chunk math in bf16
     attn_bf16_scores: bool = False        # flash score/prob blocks in bf16
-    flash_impl: str = "unrolled"          # unrolled (exact FLOP count) |
-                                          # scan (bounded-liveness memory)
-    ssd_impl: str = "parallel"            # parallel | scan (same trade)
     param_dtype: str = "float32"          # float32 | bfloat16 (f32 master
                                           # weights live in the optimizer)
     loss_impl: str = "full"               # full | chunked (vocab-streamed CE)
@@ -141,21 +134,6 @@ class ArchConfig:
         return self.n_params - L * (full_ff - act_ff)
 
 
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                     # train | prefill | decode
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
-}
-
 ARCH_IDS = [
     "yi_6b", "qwen3_8b", "qwen2_5_14b", "granite_3_8b",
     "deepseek_v2_lite_16b", "dbrx_132b", "musicgen_medium", "rwkv6_7b",
@@ -167,9 +145,3 @@ def get_config(arch_id: str, reduced: bool = False) -> ArchConfig:
     mod = importlib.import_module(f"repro.configs.{arch_id}")
     return mod.reduced() if reduced else mod.CONFIG
 
-
-def shape_applicable(cfg: ArchConfig, shape: ShapeSpec) -> bool:
-    """long_500k only for sub-quadratic families (DESIGN.md §Arch-applicability)."""
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False
-    return True

@@ -1,7 +1,7 @@
 """Public, jit-compiled entry points for the kernel package.
 
 Every op dispatches between the Pallas kernel and the pure-jnp reference
-path (used by the dry-run so XLA's SPMD partitioner sees plain HLO).
+path.
 
 ``use_pallas=True`` compiles the Mosaic kernel for the TPU; off a TPU it
 fails rather than fall back. Pass ``interpret=True`` (through ``**kw``) to

@@ -1,8 +1,7 @@
-"""Production mesh construction + the shared data-parallel axis spec.
+"""Mesh construction + the shared data-parallel axis spec.
 
 Mesh builders are functions (not module-level constants) so importing this
-module never touches jax device state — the dry-run must set XLA_FLAGS
-before first init.
+module never touches jax device state.
 
 :class:`AxisSpec` / :func:`shard_slices` are the mesh-tier language the DB
 shard tier reuses: ``db/shard.py`` mirrors the ``data`` axis across N
@@ -56,14 +55,6 @@ def shard_slices(n_rows: int, n_shards: int) -> list[slice]:
         out.append(slice(start, stop))
         start = stop
     return out
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: (16, 16) = (data, model) — 256 chips (v5e pod).
-    Multi-pod: (2, 16, 16) = (pod, data, model) — 512 chips across DCI."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes=("data", "model")):

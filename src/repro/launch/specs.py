@@ -1,40 +1,11 @@
-"""ShapeDtypeStruct stand-ins for every model input (no device allocation).
-
-``input_specs(cfg, shape)`` returns the batch spec for the shape's kind:
-  train    {tokens|embeds, labels}          (global_batch, seq)
-  prefill  {tokens|embeds}                  (global_batch, seq)
-  decode   {tokens|embeds} one new token + KV cache of seq_len
-
-Stub frontends ([audio]/[vlm]) provide precomputed frame/patch embeddings,
-per the assignment.
-"""
+"""ShapeDtypeStruct stand-ins for a model's parameters (no device
+allocation)."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, ShapeSpec
+from repro.configs.base import ArchConfig
 from repro.nn.model import LM
-
-SDS = jax.ShapeDtypeStruct
-
-
-def batch_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict:
-    b = shape.global_batch
-    s = shape.seq_len if shape.kind != "decode" else 1
-    if cfg.stub_frontend:
-        batch = {"embeds": SDS((b, s, cfg.d_model), jnp.float32)}
-    else:
-        batch = {"tokens": SDS((b, s), jnp.int32)}
-    if shape.kind == "train":
-        batch["labels"] = SDS((b, s), jnp.int32)
-    return batch
-
-
-def cache_specs(cfg: ArchConfig, shape: ShapeSpec):
-    lm = LM(cfg)
-    return jax.eval_shape(
-        lambda: lm.init_cache(shape.global_batch, shape.seq_len))
 
 
 def params_specs(cfg: ArchConfig):

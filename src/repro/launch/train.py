@@ -1,8 +1,7 @@
 """Production training launcher.
 
 On a real fleet each host runs this with its process index; here it runs
-the same code path single-host (``dryrun.py`` compiles the production
-meshes ahead of time, in a process of its own).
+the same code path single-host.
 
     PYTHONPATH=src python -m repro.launch.train --arch yi_6b --reduced \
         --steps 50 --seq 128 --batch 8 [--mesh 2x2]

@@ -4,7 +4,7 @@ Conventions:
   * params are nested dicts of jnp arrays; init functions take an rng key
     and return the dict. Stacked-layer params get a leading L axis and are
     consumed by ``lax.scan`` (scan-over-layers keeps HLO size and compile
-    time O(1) in depth — required for the 40-cell dry-run).
+    time O(1) in depth).
   * compute dtype is bf16 (params stored f32, cast at use); softmax,
     normalisation statistics and losses are f32.
 """
@@ -208,10 +208,8 @@ def attend_flash(q, k, v, chunk: int = 1024, q_offset: int = 0,
 
     Unrolled q/kv chunk loops: strictly-future blocks are *not emitted*, so
     the compiled HLO carries only the ~S²/2 causal work and O(chunk²) live
-    score blocks — this is what lets prefill_32k fit HBM and is the
-    §Perf lever that halves the attention compute term vs a dense mask.
-    Unrolled (not lax.scan) so the dry-run's cost_analysis counts every
-    block (scan bodies are counted once — see EXPERIMENTS.md §Dry-run).
+    score blocks, half the attention compute of a dense mask. When
+    ``chunk`` does not divide both lengths it falls back to ``attend``.
     """
     b, hq, sq, dh = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -235,7 +233,7 @@ def attend_flash(q, k, v, chunk: int = 1024, q_offset: int = 0,
             v_c = v[:, :, i * chunk:(i + 1) * chunk].astype(sdt)
             # with bf16_scores the S and P blocks — the dominant HBM
             # traffic of long-context attention — stay bf16; the online
-            # max/normaliser statistics remain f32 (§Perf lever)
+            # max/normaliser statistics remain f32
             s = (jnp.einsum("bhgqd,bhkd->bhgqk", q_c, k_c,
                             preferred_element_type=jnp.float32) * scale)
             if causal and (i + 1) * chunk > q_offset + c * chunk:
@@ -259,76 +257,6 @@ def attend_flash(q, k, v, chunk: int = 1024, q_offset: int = 0,
 def auto_chunk(seq_len: int) -> int:
     """Flash chunk size: ≥1024, ≤4096, ~seq/8 (bounds HLO size at 32k)."""
     return max(1024, min(4096, seq_len // 8))
-
-
-def attend_flash_scan(q, k, v, chunk: int = 1024, q_offset: int = 0,
-                      causal: bool = True, scale: float | None = None):
-    """attend_flash with the kv loop as a ``lax.scan``: identical math,
-    but the compiled program provably reuses one block of buffers per
-    step — the memory model the dry-run reports (the unrolled twin is
-    used for exact FLOP accounting; the Pallas kernel is the TPU runtime
-    path). Tested equal to attend_flash."""
-    b, hq, sq, dh = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
-    group = hq // hkv
-    scale = dh ** -0.5 if scale is None else scale
-    if sq % chunk or skv % chunk:
-        return attend(q, k, v, causal=causal, q_offset=q_offset,
-                      scale=scale)
-    qg = q.reshape(b, hkv, group, sq, dh)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    outs = []
-    for c in range(sq // chunk):
-        q_c = qg[:, :, :, c * chunk:(c + 1) * chunk].astype(jnp.float32)
-        hi_pos = q_offset + (c + 1) * chunk
-        n_kv = skv // chunk if not causal else -(-hi_pos // chunk)
-
-        def body(carry, i):
-            m, l, acc = carry
-            k_c = jax.lax.dynamic_slice_in_dim(kf, i * chunk, chunk, axis=2)
-            v_c = jax.lax.dynamic_slice_in_dim(vf, i * chunk, chunk, axis=2)
-            s = jnp.einsum("bhgqd,bhkd->bhgqk", q_c, k_c) * scale
-            if causal:
-                qpos = (q_offset + c * chunk +
-                        jnp.arange(chunk)[:, None])
-                kpos = i * chunk + jnp.arange(chunk)[None, :]
-                s = jnp.where((qpos >= kpos)[None, None, None], s, -1e30)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + p.sum(axis=-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "bhgqk,bhkd->bhgqd", p, v_c)
-            return (m_new, l, acc), None
-
-        init = (jnp.full(q_c.shape[:-1], -1e30, jnp.float32),
-                jnp.zeros(q_c.shape[:-1], jnp.float32),
-                jnp.zeros(q_c.shape[:-1] + (v.shape[-1],), jnp.float32))
-        (m, l, acc), _ = jax.lax.scan(body, init, jnp.arange(n_kv))
-        outs.append(acc / l[..., None])
-    out = jnp.concatenate(outs, axis=3)
-    return out.reshape(b, hq, sq, v.shape[-1]).astype(q.dtype)
-
-
-def attend_chunked(q, k, v, chunk: int = 2048, q_offset: int = 0,
-                   scale: float | None = None):
-    """Causal attention computed per q-chunk against only the kv prefix it
-    can see — skips strictly-future kv, halving score FLOPs vs the dense
-    mask (beyond-paper §Perf optimisation; the Pallas flash kernel is the
-    TPU-runtime twin of this HLO-level schedule)."""
-    b, hq, sq, dh = q.shape
-    if sq <= chunk:
-        return attend(q, k, v, causal=True, q_offset=q_offset, scale=scale)
-    assert sq % chunk == 0
-    outs = []
-    for c in range(sq // chunk):
-        lo = c * chunk
-        kv_hi = q_offset + lo + chunk
-        outs.append(attend(q[:, :, lo:lo + chunk], k[:, :, :kv_hi],
-                           v[:, :, :kv_hi], causal=True,
-                           q_offset=q_offset + lo, scale=scale))
-    return jnp.concatenate(outs, axis=2)
 
 
 def merge_heads(x):
@@ -360,8 +288,8 @@ def mla_qkv(p, x, n_heads: int, d_nope: int, d_rope: int, d_v: int,
 
     The latent c_kv (B,S,kv_lora) + shared k_rope (B,S,d_rope) are what a
     serving cache stores — the paper-style memory saving; here we expand to
-    full heads for the attention product (absorbed-matmul is a further
-    runtime optimisation, see DESIGN.md)."""
+    full heads for the attention product (the decode path,
+    ``LM._mla_block_decode``, absorbs the up-projections instead)."""
     b, s, _ = x.shape
     q = jnp.dot(x, cdt(p["wq"])).reshape(b, s, n_heads, d_nope + d_rope)
     q = q.transpose(0, 2, 1, 3)

@@ -9,15 +9,15 @@ Families (configs.base.ArchConfig.family):
   hybrid  — Mamba-2 backbone + shared attention block (zamba2)
 
 Structure is scan-over-layers (stacked params, leading L axis) so HLO size
-and compile time are depth-independent — a hard requirement for the 40-cell
-multi-pod dry-run. Heterogeneous layers (DeepSeek's leading dense-FFN layer,
-Zamba2's shared block every 6 layers) live outside the scanned stack.
+and compile time are depth-independent. Heterogeneous layers (DeepSeek's
+leading dense-FFN layer, Zamba2's shared block every 6 layers) live outside
+the scanned stack.
 
 Entry points consumed by the launcher:
   init(key) → params
-  loss_fn(params, batch) → (scalar loss, metrics)        [train_4k]
-  prefill(params, batch) → (last-token logits, cache)    [prefill_32k]
-  decode_step(params, batch, cache, pos) → (logits, cache)  [decode shapes]
+  loss_fn(params, batch) → (scalar loss, metrics)
+  prefill(params, batch) → (last-token logits, cache)
+  decode_step(params, batch, cache, pos) → (logits, cache)
 
 Named scopes mark the layers on the profiler's clock (compile-time metadata
 only): ``lm.embed``, ``lm.layers`` (every scanned stack), ``lm.attention``,
@@ -52,36 +52,18 @@ class LM:
         self.cfg = cfg
 
     def _scan(self, body, carry, xs):
-        """lax.scan over the layer stack, or the same scan fully unrolled
-        when ``cfg.scan_layers`` is False. The dry-run unrolls so that
-        cost_analysis counts every layer (rolled scan bodies are counted
-        once); training examples scan for O(1)-in-depth compile time.
-        Using ``lax.scan(unroll=n)`` — not a hand-written Python loop —
-        keeps both paths bitwise identical (same slicing and stacking ops,
-        same bf16 rounding), which test_scan_and_unrolled_paths_agree
-        pins."""
-        unroll = (1 if self.cfg.scan_layers
-                  else max(jax.tree.leaves(xs)[0].shape[0], 1))
+        """``lax.scan`` over the layer stack: compile time is O(1) in
+        depth."""
         with jax.named_scope("lm.layers"):
-            return jax.lax.scan(body, carry, xs, unroll=unroll)
+            return jax.lax.scan(body, carry, xs)
 
     def _attend_full(self, q, k, v):
-        """Full-sequence attention dispatch (cfg.attn_impl)."""
-        cfg = self.cfg
+        """Causal full-sequence attention: blocked online softmax at
+        ``auto_chunk``."""
         s = q.shape[2]
-        chunk = cfg.attn_chunk or L.auto_chunk(s)
-        scale = self._attn_scale(q.shape[-1])
-        if cfg.attn_impl == "flash":
-            if cfg.flash_impl == "scan":
-                return L.attend_flash_scan(q, k, v, chunk=min(chunk, s),
-                                           scale=scale)
-            return L.attend_flash(q, k, v, chunk=min(chunk, s),
-                                  bf16_scores=cfg.attn_bf16_scores,
-                                  scale=scale)
-        if cfg.attn_impl == "chunked":
-            return L.attend_chunked(q, k, v, chunk=min(chunk, s),
-                                    scale=scale)
-        return L.attend(q, k, v, causal=True, scale=scale)
+        return L.attend_flash(q, k, v, chunk=min(L.auto_chunk(s), s),
+                              bf16_scores=self.cfg.attn_bf16_scores,
+                              scale=self._attn_scale(q.shape[-1]))
 
     # granite's scalings; at the neutral defaults each is the plain
     # operation, with nothing added to the program
@@ -285,7 +267,6 @@ class LM:
                     cfg.ssm.d_state, cfg.ssm.d_conv)
             o, st = S.mamba2_mixer(p["mixer"], norm(p["norm1"], x), dims,
                                    state=cache, chunk=cfg.ssm.chunk,
-                                   ssd_impl=cfg.ssd_impl,
                                    compute_dtype=(jnp.bfloat16
                                                   if cfg.ssm_bf16
                                                   else jnp.float32))
@@ -450,7 +431,7 @@ class LM:
             if self.cfg.loss_impl == "onehot":
                 # gold logit via masked sum — unlike take_along_axis this
                 # never gathers across the vocab(model)-sharded dim: GSPMD
-                # lowers the reduction to a partial sum + psum (§Perf lever)
+                # lowers the reduction to a partial sum + psum
                 vpos = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
                                                 logits.ndim - 1)
                 gold = jnp.sum(jnp.where(vpos == labels[..., None], logits,
@@ -464,7 +445,7 @@ class LM:
     def _loss_chunked(self, params, batch):
         """Vocab-streamed cross-entropy: the (B,S,V) f32 logits tensor is
         never materialised — logsumexp and the gold logit accumulate over
-        vocab chunks (beyond-paper memory optimisation, §Perf)."""
+        vocab chunks (beyond-paper memory optimisation)."""
         cfg = self.cfg
         x, aux = self.backbone(params, batch)
         x = L.split_tokens(x, over_model=True)

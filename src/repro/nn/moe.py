@@ -3,8 +3,7 @@
 The router's output *is* the paper's relation ``{[i, j, v]}``: token i is
 assigned to expert j with gate value v. The two execution strategies the
 paper benchmarks against each other (relational vs array data type) both
-exist here, selectable per config — the dry-run/§Perf measures them at
-datacenter scale:
+exist here, selectable per config (``MoEConfig.impl``):
 
 ``impl="einsum"`` — the ARRAY representation (paper Section 5): the
     assignment is materialised per token-group as a dense one-hot
@@ -140,72 +139,21 @@ def _moe_einsum(p, xg, cfg: MoEConfig, gates, idx):
 # relational representation: sort (join) + segment sum (group-by), grouped
 # ---------------------------------------------------------------------------
 
-def _moe_sort_one(p, x, cfg: MoEConfig, gates, idx):
-    """x: (g, d); gates/idx: (g, k) — one group's relation."""
-    g, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    cap = _capacity(g, cfg)
-    # the relation, rank-major to match the einsum path's drop priority
-    expert_s = idx.T.reshape(-1)                    # (S,) S = k·g
-    token_s = jnp.tile(jnp.arange(g, dtype=jnp.int32), k)
-    gate_s = gates.T.reshape(-1)
-    order = jnp.argsort(expert_s, stable=True)      # sort-based aggregation
-    expert_sorted = expert_s[order]
-    token_sorted = token_s[order]
-    gate_sorted = gate_s[order]
-    counts = jnp.bincount(expert_s, length=e)
-    seg_start = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                                 jnp.cumsum(counts)[:-1]])
-    pos_sorted = (jnp.arange(k * g, dtype=jnp.int32)
-                  - seg_start[expert_sorted])
-    keep = pos_sorted < cap
-    # JOIN: gather token rows; scatter into per-expert capacity buckets
-    xs_slots = x[token_sorted]
-    buf = jnp.zeros((e, cap, d), x.dtype)
-    buf = buf.at[expert_sorted, jnp.where(keep, pos_sorted, cap)].add(
-        xs_slots, mode="drop")
-    ys = _expert_ffn(p, buf)
-    # gather back per slot; GROUP BY token, SUM (segment sum)
-    y_slots = ys[expert_sorted, pos_sorted] * keep[:, None]
-    weighted = y_slots.astype(jnp.float32) * gate_sorted[:, None]
-    out = jax.ops.segment_sum(weighted, token_sorted, num_segments=g)
-    return out.astype(x.dtype)
-
-
-# ---------------------------------------------------------------------------
-# relational representation with ENGINE SUPPORT: shard_map expert-owner plan
-# ---------------------------------------------------------------------------
-# The paper's conclusion — the relational representation needs engine
-# support (sort-based aggregation, §8) — repeats at cluster scale: under
-# pure GSPMD the sort/scatter plan communicates *more* than the one-hot
-# einsum (measured, EXPERIMENTS.md §Perf). shard_map is that engine
-# support: each (data, model) device routes its token shard, fills
-# capacity buckets ONLY for the experts it owns, runs the local expert
-# GEMMs, and partial-combines; a single psum over 'model' replaces both
-# the dispatch all-to-all and the one-hot einsums.
-
-_SHARD_CTX: dict = {"mesh": None, "dp": None}
-
-
-def set_moe_mesh(mesh, dp_axes):
-    """Install the mesh for impl='shard' (dryrun/trainer call this)."""
-    _SHARD_CTX["mesh"] = mesh
-    _SHARD_CTX["dp"] = dp_axes
-
-
 def _moe_sort_local(p_wi, p_wg, p_wo, x, cfg, gates, idx, e_lo, e_loc,
                     cap):
-    """Bucket-fill + expert GEMM + combine for the local expert range
-    [e_lo, e_lo + e_loc). Slots outside the range drop like non-matching
-    join tuples."""
+    """One group's relation (x: (g, d); gates/idx: (g, k)) through the
+    experts [e_lo, e_lo + e_loc), whose weights are ``p_w*``. Slots outside
+    the range drop like non-matching join tuples, so partials over a
+    partition of the experts sum to the result over all of them."""
     g, d = x.shape
     k = cfg.top_k
-    expert_s = idx.T.reshape(-1) - e_lo
+    # the relation, rank-major to match the einsum path's drop priority
+    expert_s = idx.T.reshape(-1) - e_lo                 # (S,) S = k·g
     token_s = jnp.tile(jnp.arange(g, dtype=jnp.int32), k)
     gate_s = gates.T.reshape(-1)
     owned = (expert_s >= 0) & (expert_s < e_loc)
     expert_s = jnp.where(owned, expert_s, e_loc)        # park in drop bucket
-    order = jnp.argsort(expert_s, stable=True)
+    order = jnp.argsort(expert_s, stable=True)      # sort-based aggregation
     expert_sorted = expert_s[order]
     token_sorted = token_s[order]
     gate_sorted = jnp.where(owned[order], gate_s[order], 0.0)
@@ -215,14 +163,14 @@ def _moe_sort_local(p_wi, p_wg, p_wo, x, cfg, gates, idx, e_lo, e_loc,
     pos_sorted = (jnp.arange(k * g, dtype=jnp.int32)
                   - seg_start[expert_sorted])
     keep = (pos_sorted < cap) & (expert_sorted < e_loc)
+    # JOIN: gather token rows; scatter into per-expert capacity buckets
     xs_slots = x[token_sorted]
     buf = jnp.zeros((e_loc, cap, d), x.dtype)
     buf = buf.at[jnp.where(keep, expert_sorted, e_loc),
                  jnp.where(keep, pos_sorted, cap)].add(xs_slots,
                                                        mode="drop")
-    h = jnp.einsum("ecd,edf->ecf", buf, cdt(p_wi))
-    gt = jnp.einsum("ecd,edf->ecf", buf, cdt(p_wg))
-    ys = jnp.einsum("ecf,efd->ecd", h * jax.nn.silu(gt), cdt(p_wo))
+    ys = _expert_ffn({"wi": p_wi, "wg": p_wg, "wo": p_wo}, buf)
+    # gather back per slot; GROUP BY token, SUM (segment sum)
     y_slots = ys[jnp.where(keep, expert_sorted, 0),
                  jnp.where(keep, pos_sorted, 0)] * keep[:, None]
     weighted = y_slots.astype(jnp.float32) * gate_sorted[:, None]
@@ -230,33 +178,10 @@ def _moe_sort_local(p_wi, p_wg, p_wo, x, cfg, gates, idx, e_lo, e_loc,
     return out.astype(x.dtype)
 
 
-def _moe_shard(p, x, cfg: MoEConfig):
-    """shard_map expert-owner execution. x: (T, d) flat tokens."""
-    from jax.sharding import PartitionSpec as P
-
-    mesh, dp = _SHARD_CTX["mesh"], _SHARD_CTX["dp"]
-    mp = mesh.shape["model"]
-    e_loc = cfg.n_experts // mp
-    t = x.shape[0]
-    dp_n = 1
-    for a in dp:
-        dp_n *= mesh.shape[a]
-    t_loc = t // dp_n if t % dp_n == 0 else t
-    cap = _capacity(t_loc, cfg)
-
-    def local(x_loc, router, wi, wg, wo):
-        gates, idx, _ = _route({"router": router}, x_loc, cfg)
-        e_lo = jax.lax.axis_index("model") * e_loc
-        partial = _moe_sort_local(wi, wg, wo, x_loc, cfg, gates, idx,
-                                  e_lo, e_loc, cap)
-        return jax.lax.psum(partial, "model")
-
-    x_spec = P(dp, None) if t % dp_n == 0 else P(None, None)
-    return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(x_spec, P(), P("model", None, None),
-                  P("model", None, None), P("model", None, None)),
-        out_specs=x_spec)(x, p["router"], p["wi"], p["wg"], p["wo"])
+def _moe_sort_one(p, x, cfg: MoEConfig, gates, idx):
+    """x: (g, d); gates/idx: (g, k) — one group's relation, every expert."""
+    return _moe_sort_local(p["wi"], p["wg"], p["wo"], x, cfg, gates, idx,
+                           0, cfg.n_experts, _capacity(x.shape[0], cfg))
 
 
 def moe_ffn(p, x, cfg: MoEConfig):
@@ -267,11 +192,9 @@ def moe_ffn(p, x, cfg: MoEConfig):
         g = t                                        # tiny/odd batches
     xg = x.reshape(t // g, g, d)
     gates, idx, aux = _route(p, xg, cfg)
-    if cfg.impl == "shard" and _SHARD_CTX["mesh"] is not None:
-        out = _moe_shard(p, x, cfg)
-    elif cfg.impl == "einsum":
+    if cfg.impl == "einsum":
         out = _moe_einsum(p, xg, cfg, gates, idx).reshape(t, d)
-    elif cfg.impl in ("sort", "shard"):              # shard falls back
+    elif cfg.impl == "sort":
         out = jax.vmap(
             lambda xx, gg, ii: _moe_sort_one(p, xx, cfg, gg, ii)
         )(xg, gates, idx).reshape(t, d)

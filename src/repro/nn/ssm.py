@@ -1,9 +1,8 @@
 """Recurrent sequence mixers: RWKV-6 (Finch) and Mamba-2 (SSD).
 
 The paper's technique (matmul-as-join) does not apply to these data-dependent
-recurrences (DESIGN.md §Arch-applicability) — they are implemented as
-first-class JAX layers so the assigned ``rwkv6-7b`` and ``zamba2-2.7b``
-architectures run without it.
+recurrences — they are implemented as first-class JAX layers so the
+assigned ``rwkv6-7b`` and ``zamba2-2.7b`` architectures run without it.
 
 RWKV-6 time-mix: per-head matrix state S (N×N), *vector*-valued
 data-dependent decay w_t (the Finch contribution, arXiv:2404.05892):
@@ -14,7 +13,6 @@ data-dependent decay w_t (the Finch contribution, arXiv:2404.05892):
 Executed as a ``lax.scan`` over time (vectorised over batch × heads). A
 chunkwise-parallel form exists (GLA-style) but its factorised decay
 ``exp(−a_i)`` overflows f32 for fast-decaying channels; the scan is exact.
-See EXPERIMENTS.md §Perf for the memory/FLOP trade discussion.
 
 Mamba-2 SSD: *scalar*-per-head decay makes the chunked form stable, so we
 implement the block-decomposition of the SSD paper (arXiv:2405.21060):
@@ -167,7 +165,7 @@ def ssd_chunked(x, a, b_in, c_in, chunk: int = 64, h0=None,
     """Mamba-2 SSD. x: (B,S,H,P), a: (B,S,H) log-decay (≤0),
     b_in/c_in: (B,S,N). Returns (y (B,S,H,P), h_fin (B,H,N,P)).
     ``compute_dtype=bf16`` keeps the big chunk tensors low-precision
-    (decay cumsums stay f32) — §Perf memory lever."""
+    (decay cumsums stay f32) to save memory."""
     bsz, s, h, p = x.shape
     n = b_in.shape[-1]
     assert s % chunk == 0 or s == 1
@@ -216,44 +214,6 @@ def ssd_chunked(x, a, b_in, c_in, chunk: int = 64, h0=None,
     return y.astype(x.dtype), h_fin
 
 
-def ssd_scan(x, a, b_in, c_in, chunk: int = 64, h0=None,
-             compute_dtype=jnp.float32):
-    """ssd_chunked with one ``lax.scan`` over chunks: identical math, but
-    the per-chunk decay matrix L (B,H,C,C) and states exist for ONE chunk
-    at a time — the memory model for the dry-run (the parallel form is the
-    FLOP-accounting twin). Tested equal to ssd_chunked/ssd_naive."""
-    bsz, s, h, p = x.shape
-    n = b_in.shape[-1]
-    if s == 1 or s % chunk:
-        return ssd_chunked(x, a, b_in, c_in, chunk=chunk, h0=h0,
-                           compute_dtype=compute_dtype)
-    nc = s // chunk
-    xs = x.reshape(bsz, nc, chunk, h, p).transpose(1, 0, 2, 3, 4) \
-        .astype(jnp.float32)
-    As = a.reshape(bsz, nc, chunk, h).transpose(1, 0, 3, 2)  # (nc,B,H,C)
-    Bs = b_in.reshape(bsz, nc, chunk, n).transpose(1, 0, 2, 3) \
-        .astype(jnp.float32)
-    Cs = c_in.reshape(bsz, nc, chunk, n).transpose(1, 0, 2, 3) \
-        .astype(jnp.float32)
-
-    def body(hprev, inp):
-        xc, ac, bc, cc = inp                       # (B,C,H,P),(B,H,C),...
-        a_cum = jnp.cumsum(ac, axis=-1)            # (B,H,C)
-        L = jnp.exp(_segsum(ac))                   # (B,H,C,C)
-        y_diag = jnp.einsum("bln,bsn,bhls,bshp->blhp", cc, bc, L, xc)
-        y_off = jnp.einsum("bln,bhl,bhnp->blhp", cc, jnp.exp(a_cum),
-                           hprev)
-        decay_states = jnp.exp(a_cum[..., -1:] - a_cum)
-        st = jnp.einsum("bcn,bhc,bchp->bhnp", bc, decay_states, xc)
-        hnew = hprev * jnp.exp(a_cum[..., -1])[..., None, None] + st
-        return hnew, y_diag + y_off
-
-    h_init = jnp.zeros((bsz, h, n, p), jnp.float32) if h0 is None else h0
-    h_fin, ys = jax.lax.scan(body, h_init, (xs, As, Bs, Cs))
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(bsz, s, h, p)
-    return y.astype(x.dtype), h_fin
-
-
 def ssd_naive(x, a, b_in, c_in, h0=None):
     """Step-by-step oracle for ssd_chunked."""
     bsz, s, h, p = x.shape
@@ -271,8 +231,7 @@ def ssd_naive(x, a, b_in, c_in, h0=None):
 
 
 def mamba2_mixer(p, xin, dims: tuple[int, int, int, int], state=None,
-                 chunk: int = 64, ssd_impl: str = "parallel",
-                 compute_dtype=jnp.float32):
+                 chunk: int = 64, compute_dtype=jnp.float32):
     """Full Mamba-2 block mixer. xin: (B,S,d); dims (static) =
     (d_inner, head_dim, d_state, d_conv).
     state: (conv_state (B, d_conv-1, d_inner+2N), h (B,H,N,P)) or None."""
@@ -296,9 +255,8 @@ def mamba2_mixer(p, xin, dims: tuple[int, int, int, int], state=None,
     xh = (xpart.reshape(b, s, n_heads, head_p)
           * dt_f[..., None].astype(xpart.dtype))
     h0 = None if state is None else state[1]
-    ssd = ssd_scan if ssd_impl == "scan" else ssd_chunked
-    y, h_fin = ssd(xh, a, b_in, c_in, chunk=min(chunk, s), h0=h0,
-                   compute_dtype=compute_dtype)
+    y, h_fin = ssd_chunked(xh, a, b_in, c_in, chunk=min(chunk, s), h0=h0,
+                           compute_dtype=compute_dtype)
     y = y + p["d_skip"][None, None, :, None].astype(y.dtype) * xh
     y = y.reshape(b, s, d_inner)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z))

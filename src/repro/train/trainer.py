@@ -1,11 +1,11 @@
 """Training loop: microbatched grad accumulation, clipping, optimizer,
 checkpoint/restart, straggler monitoring.
 
-``make_train_step`` builds the pure step function the dry-run lowers; the
-``Trainer`` class wraps it with the operational substrate (fault tolerance,
-checkpoint cadence, metrics) for the runnable examples, on one device or,
-given a ``mesh``, with its state and batches sharded over the mesh by the
-rules of ``launch/sharding.py``.
+``make_train_step`` builds the pure step function; the ``Trainer`` class
+wraps it with the operational substrate (fault tolerance, checkpoint
+cadence, metrics) for the runnable examples, on one device or, given a
+``mesh``, with its state and batches sharded over the mesh by the rules
+of ``launch/sharding.py``.
 """
 from __future__ import annotations
 
@@ -31,9 +31,9 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
 
     With ``grad_accum > 1`` the global batch is split along axis 0 into
     microbatches accumulated in a ``lax.scan`` — activation memory drops by
-    the accumulation factor while keeping the same global batch (a standard
-    memory-roofline lever, see §Perf). Clipping and the update run under the
-    named scopes ``train.clip`` and ``train.optimizer``.
+    the accumulation factor while keeping the same global batch. Clipping
+    and the update run under the named scopes ``train.clip`` and
+    ``train.optimizer``.
     """
     vg = jax.value_and_grad(loss_fn, has_aux=True)
 

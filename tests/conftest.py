@@ -3,8 +3,8 @@ import sys
 import tempfile
 
 # NOTE: do NOT set XLA_FLAGS / host device count here — smoke tests and
-# benchmarks must see the single real CPU device (the 512-device mesh is
-# exclusively the dry-run's, launched as its own process).
+# benchmarks must see the single real CPU device (tests that need a mesh
+# of virtual devices start a process of their own).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Isolate the persistent rendered-SQL plan cache: without this, every

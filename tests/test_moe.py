@@ -81,8 +81,8 @@ def test_property_impls_agree(t, e, k, seed):
 
 def test_shard_partials_sum_to_full():
     """Expert-owner partial combine: summing per-owner partials over a
-    partition of the expert range equals the full relational result —
-    the correctness core of the shard_map (impl='shard') plan."""
+    partition of the expert range equals the relational result over
+    every expert (``_moe_sort_one``, the sort path's body)."""
     from repro.nn.moe import _capacity, _moe_sort_local, _moe_sort_one, _route
     cfg_f, p, x = make(t=40, e=8, k=2, seed=5)
     cfg = cfg_f("sort")

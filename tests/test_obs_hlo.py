@@ -1,8 +1,10 @@
-"""``repro.obs.hlo.collectives`` on handwritten HLO in the two forms the
+"""``repro.obs.hlo.collectives`` on handwritten HLO in the forms the
 compilers print: known trip counts (CPU) and loop conditions against a
 constant, asynchronous collective fusions under one channel and the
-reduce-scatter fusion (TPU)."""
+reduce-scatter fusion (TPU), and operands printed with their types."""
 from __future__ import annotations
+
+import pytest
 
 from repro.obs.hlo import collectives
 
@@ -93,3 +95,41 @@ def test_a_program_without_collectives_reads_zero():
             "  ROOT %n = f32[4]{0} negate(%p)\n}\n")
     assert all(v == {"count": 0, "bytes": 0}
                for v in collectives(text).values())
+
+
+# one collective of each kind over iota replica groups of 512 devices, the
+# operands typed as ``compiled.as_text()`` prints them
+ONE_EACH = """HloModule one_each
+
+%add (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %s = f32[] add(f32[] %a, f32[] %b)
+}
+
+ENTRY %main (x: f32[1024,512], y: bf16[8,256], z: f32[16384,64], w: f32[128]) -> f32[128] {
+  %x = f32[1024,512]{1,0} parameter(0)
+  %y = bf16[8,256]{1,0} parameter(1)
+  %z = f32[16384,64]{1,0} parameter(2)
+  %w = f32[128]{0} parameter(3)
+  %ar = f32[1024,512]{1,0} all-reduce(f32[1024,512]{1,0} %x), channel_id=1, replica_groups=[32,16]<=[512], use_global_device_ids=true, to_apply=%add
+  %ag = bf16[256,256]{1,0} all-gather(bf16[8,256]{1,0} %y), channel_id=2, replica_groups=[16,32]<=[512], dimensions={0}
+  %rs = f32[64,64]{1,0} reduce-scatter(f32[16384,64]{1,0} %z), channel_id=3, replica_groups=[2,256]<=[512], dimensions={0}, to_apply=%add
+  %sum = f32[1024,512]{1,0} add(f32[1024,512]{1,0} %ar, f32[1024,512]{1,0} %ar)
+  ROOT %cp = f32[128]{0} collective-permute(f32[128]{0} %w), channel_id=4, source_target_pairs={{0,1}}
+}
+"""
+
+
+@pytest.mark.parametrize("kind,nbytes", [
+    ("all-reduce", 1024 * 512 * 4),
+    ("all-gather", 8 * 256 * 2),
+    ("reduce-scatter", 16384 * 64 * 4),
+    ("collective-permute", 128 * 4),
+])
+def test_one_collective_of_each_kind_over_iota_groups(kind, nbytes):
+    """Each collective runs once and moves its typed operand's bytes; the
+    plain ``add`` beside them counts as nothing."""
+    got = collectives(ONE_EACH)
+    assert got[kind] == {"count": 1, "bytes": nbytes}
+    assert got["all-to-all"] == {"count": 0, "bytes": 0}

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.configs.base import SHAPES, get_config
+from repro.configs.base import get_config
 from repro.launch import sharding as sh
 
 

@@ -15,10 +15,12 @@ cell, row-major) the sort order is fixed by the shape: the tuples come in
 runs of ``k`` per outer index ``i`` with ``j`` = 0…k−1, so the join is the
 identity alignment against the inner relation's clusters and the group-by
 is an *ordered reduction over each fixed run* — the sort-based aggregation
-with continuous output that the paper's §8 proposes as future work. A left
-operand with fewer tuples than cells (sparse or padded) has no fixed runs:
-its join gathers the matching inner tuples and its group-by is a *segment
-sum* (a scatter-add) over ``i``. The join intermediate (``nnz(A) × n``
+with continuous output that the paper's §8 proposes as future work. Join and
+group-by together are then one contraction over ``k``, which runs on the
+chip's matrix unit like the dense engine's product. A left operand with
+fewer tuples than cells (sparse or padded) has no fixed runs: its join
+gathers the matching inner tuples and its group-by is a *segment sum* (a
+scatter-add) over ``i``. The join intermediate (``nnz(A) × n``
 tuples before aggregation — the thousandfold blow-up of Fig. 5) is explicit
 in this formulation and is what `benchmarks/fig5_matmul_memory.py` measures.
 
@@ -29,10 +31,11 @@ one-hot matrix or the MoE token→expert assignment; padding rows carry an
 out-of-range ``i`` so the group-by drops them (scatter-drop semantics).
 
 The device ops of each step carry a named scope: ``rel.join`` (the
-multiply, with its gather on the sparse path), ``rel.group_by`` (the run
-reduction or the segment sum) and ``rel.pivot`` (dense ↔ relation), so a
-profiler trace charges the chip's time to them. Each product traced counts
-``rel.group_by.ordered`` or ``rel.group_by.scatter`` on the active tracer.
+multiply, with its gather on the sparse path), ``rel.group_by`` (the
+segment sum; a canonical product's contraction sits inside both) and
+``rel.pivot`` (dense ↔ relation), so a profiler trace charges the chip's
+time to them. Each product traced counts ``rel.group_by.ordered`` or
+``rel.group_by.scatter`` on the active tracer.
 """
 from __future__ import annotations
 
@@ -151,14 +154,17 @@ class RelTensor:
 
         A canonical ``self`` (``is_canonical()``) is sorted by ``i`` in runs
         of exactly ``k`` tuples with ``j`` = 0…k−1, so tuple ``(i, kk)``
-        meets inner cluster ``kk`` without a gather, and the group-by is a
-        reduction over each run: an ``(m, k, n)`` broadcast multiply summed
-        over ``k``, which XLA fuses into one loop without materialising the
-        join. A ``self`` with fewer tuples than cells (the sparse one-hot,
-        the MoE assignment, padded relations) gathers the inner clusters by
-        ``j`` and groups by a segment sum over ``i`` (a scatter-add); its
-        padding tuples (``i == m``) are dropped (scatter-drop), mirroring
-        the inner join discarding non-matching tuples.
+        meets inner cluster ``kk`` without a gather, and the group-by sums
+        each run: join and group-by together are one contraction over ``k``,
+        handed to the compiler as a matrix product so that the matrix unit
+        forms and sums every joined product without materialising the join.
+        Its operands are pinned at float32 (``Precision.HIGHEST``), whatever
+        precision the caller's context sets. A ``self`` with fewer tuples
+        than cells (the sparse one-hot, the MoE assignment, padded
+        relations) gathers the inner clusters by ``j`` and groups by a
+        segment sum over ``i`` (a scatter-add); its padding tuples
+        (``i == m``) are dropped (scatter-drop), mirroring the inner join
+        discarding non-matching tuples.
         """
         if self.shape[1] != other.shape[0]:
             raise ValueError(f"matmul: {self.shape} @ {other.shape}")
@@ -169,10 +175,10 @@ class RelTensor:
         rhs_rows = other.v.reshape(k, n)     # clustered by inner index
         if self.is_canonical():
             current().inc("rel.group_by.ordered")
-            with jax.named_scope("rel.join"):
-                joined = self.v.reshape(m, k, 1) * rhs_rows    # (m, k, n)
-            with jax.named_scope("rel.group_by"):
-                out = joined.sum(axis=1)
+            with jax.named_scope("rel.join"), jax.named_scope("rel.group_by"):
+                out = jnp.matmul(self.v.reshape(m, k), rhs_rows,
+                                 precision=jax.lax.Precision.HIGHEST,
+                                 preferred_element_type=jnp.float32)
         else:
             current().inc("rel.group_by.scatter")
             with jax.named_scope("rel.join"):

@@ -170,7 +170,7 @@ class TestRelTensor:
         np.testing.assert_array_equal(t.transpose().to_dense(), expect)
 
     # the group-by as a scatter: the gather-join and segment sum that a
-    # canonical left operand's ordered run reduction must agree with
+    # canonical left operand's contraction must agree with
     @staticmethod
     def scatter_matmul(rel, other):
         m, k = rel.shape
@@ -207,6 +207,27 @@ class TestRelTensor:
                                     want.v.reshape(m, n))
             self.assert_f32_product(got.v.reshape(m, n), a, b, want64)
 
+    @pytest.mark.parametrize("m,k,n", [(7, 5, 3), (64, 96, 130),
+                                       (200, 784, 10)])
+    def test_canonical_matmul_is_one_pinned_contraction(self, m, k, n):
+        """Join and group-by of a canonical left operand compile to exactly
+        one dot, with no reduce and no scatter, asked for at float32
+        ("highest") whatever precision the caller's context sets: the
+        values are the same under the default and the highest context."""
+        ra = RelTensor.from_dense(rnd(m, k))
+        rb = RelTensor.from_dense(rnd(k, n))
+        got = {}
+        for precision in ("default", "highest"):
+            with jax.default_matmul_precision(precision):
+                lowered = jax.jit(RelTensor.matmul).lower(ra, rb)
+            assert "precision = [HIGHEST, HIGHEST]" in lowered.as_text()
+            compiled = lowered.compile()
+            text = compiled.as_text()
+            assert len(re.findall(r"\bdot\(", text)) == 1
+            assert not re.search(r"\b(?:reduce|scatter)\(", text)
+            got[precision] = compiled(ra, rb).v
+        np.testing.assert_array_equal(got["default"], got["highest"])
+
     @pytest.mark.parametrize("shape", SHAPES)
     def test_canonical_to_dense_is_the_scatter(self, shape):
         rel = RelTensor.from_dense(rnd(*shape))
@@ -215,8 +236,8 @@ class TestRelTensor:
             np.testing.assert_array_equal(got, scattered)
 
     def test_group_by_counts_its_path(self):
-        """A canonical left operand takes the ordered run reduction; the
-        sparse one-hot and a padded relation take the scatter."""
+        """A canonical left operand takes the ordered path (one contraction);
+        the sparse one-hot and a padded relation take the scatter."""
         b = RelTensor.from_dense(rnd(8, 5))
         padded = RelTensor(i=jnp.array([0, 2, 3, 4, 4], jnp.int32),
                            j=jnp.array([1, 0, 7, 0, 0], jnp.int32),
@@ -334,7 +355,11 @@ class TestEngines:
     def test_relational_training_query_sorts_nothing(self, monkeypatch):
         """Every relation the training graph transposes (img, a_xh, w_ho) is
         canonical, so the compiled query holds no sort, and its weights are
-        bitwise those of the same query re-sorting each transpose by argsort."""
+        those of the same query re-sorting each transpose by argsort, within
+        float32 rounding. The two transposes give the same tuples in the
+        same order (``test_canonical_transpose_is_the_argsort_construction``),
+        but the argsort's gathered operand is not folded into the contraction
+        as the fixed permutation is, so the two queries sum in other orders."""
         spec = nn2sql.MLPSpec(40, 24, 12, 10)
         g = nn2sql.build_graph(spec)
         r = np.random.RandomState(5)
@@ -356,7 +381,7 @@ class TestEngines:
         assert re.search(r"\bsort\(", sorting.as_text())
         want = sorting(w0, x, y)
         for k in ("w_xh", "w_ho"):
-            np.testing.assert_array_equal(got[k], want[k])
+            np.testing.assert_allclose(got[k], want[k], rtol=1e-5, atol=1e-6)
 
     def test_relational_training_query_scatters_nothing(self, monkeypatch):
         """Every product of the training graph has a canonical left operand:

@@ -82,10 +82,10 @@ def test_mlp_query_carries_a_scope_per_ir_node(kind):
     compiled = scopes_in(mlp_query_hlo(kind))
     want = kinds | (rel if kind == "relational" else set())
     assert want <= lowered
-    # XLA folds the dense transposes into the products and the constants
-    # away; every other scope reaches the compiled program
-    folded = {"ir.Transpose", "ir.Const"} if kind == "dense" else {"ir.Const"}
-    assert want - folded <= compiled
+    # XLA folds the transposes into the products (each engine's product
+    # is a contraction) and the constants away; every other scope reaches
+    # the compiled program
+    assert want - {"ir.Transpose", "ir.Const"} <= compiled
     if kind == "dense":
         assert not rel & compiled
 

@@ -115,26 +115,43 @@ def test_dense_mlp_train_step_compiles_for_v5e(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 ** 30
 
 
-def test_relational_mlp_query_groups_without_a_scatter(one_chip):
+def _relational_mlp_query(one_chip):
     """A 10-iteration training query of the paper's network on the
-    relational engine: every product's group-by is a reduction over the
-    fixed runs of a canonical relation, so the program holds no scatter,
-    keeps no joined tuples in memory (1.25 GB for the 2000×784·784×200
-    product), and rounds no operand of a product to bfloat16."""
+    relational engine, compiled with no precision context around it."""
     from repro.core import nn2sql
     from repro.core.engine import Engine
 
     g = nn2sql.build_graph(nn2sql.MLPSpec(2000, 784, 200, 10))
     s = _spec(one_chip)
     w = {"w_xh": s((784, 200)), "w_ho": s((200, 10))}
-    compiled = _compile(
+    return _compile(
         lambda w, x, y: nn2sql.train(g, w, x, y, 10, Engine("relational"))[0],
         w, s((2000, 784)), s((2000, 10)))
+
+
+def test_relational_mlp_query_groups_without_a_scatter(one_chip):
+    """A 10-iteration training query of the paper's network on the
+    relational engine: every product's group-by is a reduction over the
+    fixed runs of a canonical relation, so the program holds no scatter,
+    keeps no joined tuples in memory (1.25 GB for the 2000×784·784×200
+    product), and rounds no operand of a product to bfloat16."""
+    compiled = _relational_mlp_query(one_chip)
     text = compiled.as_text()
     assert not re.search(r"\bscatter\(", text)
     for op in re.findall(r"\b(?:dot|convolution)\(.*", text):
         assert "operand_precision={highest,highest}" in op
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
+
+
+def test_relational_mlp_query_runs_its_products_on_the_mxu(one_chip):
+    """The same 10-iteration relational query holds exactly the dense
+    query's five products (two forward, three backward) as MXU ops, each at
+    float32 ("highest") with no precision context around the compile."""
+    text = _relational_mlp_query(one_chip).as_text()
+    products = re.findall(r"= \S+ (?:dot|convolution)\(.*", text)
+    assert len(products) == 5
+    for op in products:
+        assert "operand_precision={highest,highest}" in op
 
 
 def test_granite_train_step_fits_a_2x2_mesh(topo):
